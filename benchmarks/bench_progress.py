@@ -648,6 +648,19 @@ print(f"fsdp_prefetch_overlap,{overlap:.3f},fraction of the gather "
 """
 
 
+def _cpu_child_env() -> dict:
+    """Environment of a child that forces host devices: a CPU rehearsal by
+    construction, so it never reaches for an accelerator that this
+    process may already hold."""
+    env = dict(os.environ)
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def fsdp_training():
     """ZeRO-style FSDP step family (fsdp_* rows, 2x2 host devices in a
     child): the replicated unsharded baseline, the native in-program
@@ -656,11 +669,7 @@ def fsdp_training():
     continuation-chained gathers.  Baseline prints before the FSDP
     sweep so a crash in the new path still salvages it (same
     discipline as serve_collectives)."""
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_child_env()
     try:
         proc = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(_FSDP_SNIPPET)],
@@ -719,11 +728,7 @@ def debug_overhead():
     with the checkers dormant and armed — the lifecycle hooks and
     ordered locks must stay under the ~5%% budget that makes running
     tier-1 under REPRO_DEBUG=1 in CI viable."""
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_child_env()
     env.pop("REPRO_DEBUG", None)      # the child toggles it itself
     try:
         proc = subprocess.run(
@@ -842,11 +847,7 @@ def pipeline_parallelism():
     plus the measured-vs-analytic bubble row.  Baseline rows print
     before the 1F1B sweep so a crash in the new path still salvages
     them (same discipline as serve_collectives)."""
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_child_env()
     try:
         proc = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(_PIPELINE_SNIPPET)],
@@ -867,11 +868,7 @@ def recovery():
     trainer's remesh-and-retry step.  The serve row prints first so a
     crash mid-sweep salvages it (same discipline as the serve
     families)."""
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_child_env()
     try:
         proc = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(_RECOVERY_SNIPPET)],
@@ -892,11 +889,7 @@ def serve_continuous_batching():
     the 4-lane rows before starting the wide sweep, so a timeout or
     crash mid-sweep still salvages the baseline rows (same discipline
     as serve_collectives)."""
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_child_env()
     try:
         proc = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(_SERVE_CB_SNIPPET)],
@@ -918,11 +911,7 @@ def serve_collectives():
     the persistent user-space all-gather on the serve-collective
     stream.  ``serve_gain_*`` holds the user/native ratio (excluded
     from the trend gate by prefix)."""
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_child_env()
     try:
         proc = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(_SERVE_SNIPPET)],

@@ -123,6 +123,7 @@ def run():
     src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"     # host devices: never the accelerator
     try:
         proc = subprocess.run([sys.executable, "-c", textwrap.dedent(SNIPPET)],
                               capture_output=True, text=True, timeout=1500,

@@ -16,21 +16,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, list_configs
+from repro.configs.scales import SCALES
 from repro.core import ProgressEngine
 from repro.data.pipeline import PrefetchPipeline, SyntheticLM
 from repro.models import registry
 from repro.train import optimizer as opt_mod
 from repro.train.train_loop import Trainer, TrainLoopConfig
-
-SCALES = {
-    # ~1M params: fast CPU demo
-    "tiny": dict(num_layers=2, d_model=64, d_ff=128, vocab_size=512,
-                 num_heads=4, num_kv_heads=2, head_dim=16, remat_policy="none"),
-    # ~25M params: slower but meaningful loss curves on CPU
-    "small": dict(num_layers=4, d_model=256, d_ff=1024, vocab_size=4096,
-                  num_heads=8, num_kv_heads=4, head_dim=32, remat_policy="none"),
-    "full": {},
-}
 
 
 def main():

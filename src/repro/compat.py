@@ -1,11 +1,9 @@
-"""Version-tolerant wrappers for jax APIs that moved between releases.
+"""One home for the jax mesh and shard_map APIs the repo uses.
 
-The repo targets the current public surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``,
-``jax.sharding.get_abstract_mesh``); older installed versions (0.4.x)
-keep the same machinery under ``jax.experimental`` / ``jax._src.mesh``.
-Everything that needs one of these goes through this module so the
-version probe lives in exactly one place.
+The repo targets jax 0.9: ``jax.shard_map``, ``jax.make_mesh(...,
+axis_types=...)``, ``jax.set_mesh`` and
+``jax.sharding.get_abstract_mesh``.  Everything that needs one of these
+goes through this module, so a future API move is fixed in one place.
 """
 from __future__ import annotations
 
@@ -13,90 +11,36 @@ import contextlib
 
 import jax
 
-# -- shard_map ---------------------------------------------------------------
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pre-0.5: public home was jax.experimental, knob was check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_experimental(f, **kwargs)
+shard_map = jax.shard_map
 
 
-# -- axis queries ------------------------------------------------------------
 def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` (static size of a named mapped axis); on
-    0.4.x resolved from the tracing-time axis environment."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax._src import core
-    return core.get_axis_env().axis_size(axis_name)
+    """Static size of a named mapped axis."""
+    return jax.lax.axis_size(axis_name)
 
 
 def pcast(x, axis_names, *, to: str = "varying"):
-    """``jax.lax.pcast`` (explicit varying/unvarying marking inside
-    shard_map).  Older jax treats everything inside shard_map as
-    device-varying already, so the cast is the identity there."""
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is not None:
-        return fn(x, axis_names, to=to)
-    return x
-
-
-# -- mesh construction -------------------------------------------------------
-def _auto_axis_types(n: int):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
+    """Explicit varying/unvarying marking inside shard_map."""
+    return jax.lax.pcast(x, axis_names, to=to)
 
 
 def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """jax.make_mesh with Auto axis types where the kwarg exists."""
-    axis_shapes, axis_names = tuple(axis_shapes), tuple(axis_names)
-    types = _auto_axis_types(len(axis_names))
-    if types is not None:
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=types)
-    return jax.make_mesh(axis_shapes, axis_names)
+    """jax.make_mesh with every axis of type Auto."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
-# -- current-mesh context ----------------------------------------------------
 def current_mesh():
-    """The mesh set by ``set_mesh`` (or None outside any mesh context).
-
-    Returns whatever mesh object is usable as ``shard_map``'s ``mesh=``
-    argument on this jax version: the abstract mesh on ≥0.5, the
-    concrete mesh inside a ``with mesh`` / ``set_mesh`` block on 0.4.x.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax._src import mesh as mesh_lib
-    concrete = mesh_lib.thread_resources.env.physical_mesh
-    if concrete is not None and not concrete.empty:
-        return concrete
-    abstract_getter = getattr(mesh_lib, "get_abstract_mesh", None)
-    abstract_cls = getattr(jax.sharding, "AbstractMesh", None)
-    if abstract_getter is not None and abstract_cls is not None:
-        abstract = abstract_getter()
-        # early 0.4.x returns a sentinel tuple when no mesh is set
-        if isinstance(abstract, abstract_cls):
-            return abstract
-    return None
+    """The abstract mesh set by ``set_mesh`` (empty outside any mesh
+    context), usable as ``shard_map``'s ``mesh=`` argument."""
+    return jax.sharding.get_abstract_mesh()
 
 
 @contextlib.contextmanager
 def set_mesh(mesh: jax.sharding.Mesh):
-    """``with jax.set_mesh(mesh)``, or its 0.4.x equivalent: enter the
-    concrete-mesh resource context AND publish the abstract mesh so
-    ``current_mesh`` readers see it during tracing."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-        return
-    from jax._src import mesh as mesh_lib
-    with mesh_lib.set_abstract_mesh(mesh.abstract_mesh), mesh:
+    """``with jax.set_mesh(mesh)``: ``current_mesh`` readers see ``mesh``
+    during tracing."""
+    with jax.set_mesh(mesh):
         yield mesh

@@ -60,9 +60,11 @@ class ModelConfig:
     # modality frontend stub: model consumes precomputed embeddings appended
     # to the token embeddings (pixtral patch embeds)
     frontend_stub: str | None = None  # None | "audio" | "vision"
-    # attention implementation: "xla" (chunked online-softmax) |
-    # "xla_blockskip" (causal lower-triangular block schedule, ~2× fewer
-    # attention FLOPs) | "pallas"
+    # attention implementation (layers.ATTENTION_IMPLS; anything else
+    # raises): "xla" (chunked online-softmax) | "xla_blockskip" (causal
+    # lower-triangular block schedule, ~2× fewer attention FLOPs) | "ring"
+    # (sequence-sharded over the "model" axis).  The Pallas kernels in
+    # repro.kernels are not wired into the model.
     attention_impl: str = "xla"
     # pad attention heads up to a multiple of this so the head dim shards
     # over the tensor axis (zero-padded weights receive exactly zero

@@ -8,7 +8,10 @@ head (the GQA group) are processed together — q block [G, hd] hits the
 MXU as a tall-skinny GEMM against [block_k, hd].
 
 Per-sequence valid lengths mask the tail block (continuous batching
-serves sequences of different lengths from one padded cache).
+serves sequences of different lengths from one padded cache); they are
+scalar-prefetched into SMEM.  The kernel reads a head-major
+``[B, KVH, S, hd]`` cache view so each block's two minor dims are
+``(block_k, hd)``, as the TPU tiling requires.
 """
 from __future__ import annotations
 
@@ -25,6 +28,7 @@ NEG_INF = -1e30
 
 def _fd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                scale: float, block_k: int):
+    b = pl.program_id(0)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -34,16 +38,17 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[0]
+    length = len_ref[b]
     k_start = ki * block_k
 
     @pl.when(k_start < length)
     def _run():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale    # [G, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bk, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        # operands enter the MXU in their own dtype, accumulating in f32
+        q = q_ref[0, 0]                                      # [G, hd]
+        k = k_ref[0, 0]                                      # [bk, hd]
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [G,bk]
+                                preferred_element_type=jnp.float32) * scale
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < length, s, NEG_INF)
         m_prev = m_scr[...]
@@ -79,22 +84,25 @@ def flash_decode(q, k_cache, v_cache, lengths, *, block_k: int = 512,
 
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM if not interpret else None,
-                         block_shape=(1,),
-                         index_map=lambda b, h, ki: (b,)),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, ki: (b, ki, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, ki: (b, ki, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, ki: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, G, hd), lambda b, h, ki, lens: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_k, hd),
+                             lambda b, h, ki, lens: (b, h, ki, 0)),
+                pl.BlockSpec((1, 1, block_k, hd),
+                             lambda b, h, ki, lens: (b, h, ki, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, hd),
+                                   lambda b, h, ki, lens: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, hd), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
         interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), qg, k_cache.transpose(0, 2, 1, 3),
+      v_cache.transpose(0, 2, 1, 3))
     return out.reshape(B, H, hd)

@@ -8,6 +8,9 @@ max/denominator live in VMEM scratch across the kv-block grid dimension
 reduction.
 
 Grid: (batch, heads, q_blocks, kv_blocks) — kv innermost/sequential.
+The kernel works on a head-major ``[B, H, S, hd]`` view, so each block's
+two minor dims are ``(block, hd)`` as the TPU tiling requires; the
+wrapper transposes the model's ``[B, S, H, hd]`` layout in and out.
 GQA is handled in the q-head → kv-head index map (no KV repeat in HBM).
 Causality is exploited by masking; fully-masked kv blocks are skipped
 via ``pl.when`` (the 2× causal FLOP saving).
@@ -50,11 +53,12 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(should_run)
     def _run():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale   # [bq, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # [bk, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        # operands enter the MXU in their own dtype, accumulating in f32
+        q = q_ref[0, 0]                               # [bq, hd]
+        k = k_ref[0, 0]                               # [bk, hd]
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [bq,bk]
+                                preferred_element_type=jnp.float32) * scale
         if causal:
             qpos = q_abs0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -74,7 +78,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == nk - 1)
     def _fini():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
@@ -93,20 +97,20 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
         _fa_kernel, scale=scale, block_q=block_q, block_k=block_k,
         causal=causal, sq=Sq, sk=Sk)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, qi, ki: (b, ki, h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, hd),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, hd),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
             # m, l: [block_q, 1]; acc: [block_q, hd] — all VMEM-resident
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -114,4 +118,6 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+      v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)

@@ -7,8 +7,10 @@ Each op dispatches on ``impl``:
   forward runs the kernel, backward rematerializes through the
   reference formulation via ``jax.custom_vjp``).
 
-On this CPU container the kernels are validated with ``interpret=True``;
-on a real TPU the same entry points run compiled Mosaic.
+The entry points compile for the TPU (Mosaic).  A CPU caller passes
+``interpret=True`` explicitly; nothing here picks interpret mode on its
+own, so a kernel that cannot compile fails instead of silently running
+in the interpreter.
 """
 from __future__ import annotations
 
@@ -25,21 +27,13 @@ from repro.kernels.rmsnorm import rmsnorm_fwd as _rms_fwd_kernel
 from repro.kernels.ssd_scan import ssd_chunk as _ssd_kernel
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
 # ---------------------------------------------------------------------------
 # flash attention (fwd kernel; bwd via reference remat)
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_attention(q, k, v, causal: bool = True, interpret: bool | None = None):
-    itp = (not _on_tpu()) if interpret is None else interpret
-    return _fa_kernel(q, k, v, causal=causal, interpret=itp)
+def flash_attention(q, k, v, causal: bool = True, interpret: bool = False):
+    return _fa_kernel(q, k, v, causal=causal, interpret=interpret)
 
 
 def _fa_fwd(q, k, v, causal, interpret):
@@ -60,9 +54,8 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # flash decode (inference only — no vjp needed)
 # ---------------------------------------------------------------------------
 
-def flash_decode(q, k_cache, v_cache, lengths, interpret: bool | None = None):
-    itp = (not _on_tpu()) if interpret is None else interpret
-    return _fd_kernel(q, k_cache, v_cache, lengths, interpret=itp)
+def flash_decode(q, k_cache, v_cache, lengths, interpret: bool = False):
+    return _fd_kernel(q, k_cache, v_cache, lengths, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -70,10 +63,9 @@ def flash_decode(q, k_cache, v_cache, lengths, interpret: bool | None = None):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def rmsnorm(x, scale, eps: float = 1e-6, interpret: bool | None = None):
-    itp = (not _on_tpu()) if interpret is None else interpret
+def rmsnorm(x, scale, eps: float = 1e-6, interpret: bool = False):
     shape = x.shape
-    y = _rms_fwd_kernel(x.reshape(-1, shape[-1]), scale, eps, interpret=itp)
+    y = _rms_fwd_kernel(x.reshape(-1, shape[-1]), scale, eps, interpret=interpret)
     return y.reshape(shape)
 
 
@@ -83,10 +75,9 @@ def _rms_fwd(x, scale, eps, interpret):
 
 def _rms_bwd(eps, interpret, res, g):
     x, scale = res
-    itp = (not _on_tpu()) if interpret is None else interpret
     shape = x.shape
     dx, ds = _rms_bwd_kernel(x.reshape(-1, shape[-1]), scale,
-                             g.reshape(-1, shape[-1]), eps, interpret=itp)
+                             g.reshape(-1, shape[-1]), eps, interpret=interpret)
     return dx.reshape(shape), jnp.sum(ds, axis=0).astype(scale.dtype)
 
 
@@ -98,9 +89,8 @@ rmsnorm.defvjp(_rms_fwd, _rms_bwd)
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def ssd_chunk(x, b, c, dt, a_log, interpret: bool | None = None):
-    itp = (not _on_tpu()) if interpret is None else interpret
-    return _ssd_kernel(x, b, c, dt, a_log, interpret=itp)
+def ssd_chunk(x, b, c, dt, a_log, interpret: bool = False):
+    return _ssd_kernel(x, b, c, dt, a_log, interpret=interpret)
 
 
 def _ssd_fwd(x, b, c, dt, a_log, interpret):

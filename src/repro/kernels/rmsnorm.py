@@ -25,7 +25,7 @@ def _rms_bwd_kernel(x_ref, s_ref, g_ref, dx_ref, ds_ref, *, eps: float):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     inv = jax.lax.rsqrt(var + eps)
     xhat = x * inv
-    ds_ref[0, :] = jnp.sum(g * xhat, axis=0).astype(ds_ref.dtype)
+    ds_ref[0] = jnp.sum(g * xhat, axis=0, keepdims=True).astype(ds_ref.dtype)
     gs = g * s
     # d/dx of xhat·s: inv·(gs − xhat·mean(gs⊙xhat))
     dx = inv * (gs - xhat * jnp.mean(gs * xhat, axis=-1, keepdims=True))
@@ -68,12 +68,13 @@ def rmsnorm_bwd(x, scale, g, eps: float = 1e-6, *, block_rows: int = 256,
         ],
         out_specs=[
             pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
+            # [nb, 1, D]: the block's minor dims (1, D) equal the array's
+            pl.BlockSpec((1, 1, D), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((N, D), x.dtype),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, D), jnp.float32),
         ],
         interpret=interpret,
     )(x, scale, g)
-    return dx, ds
+    return dx, ds.reshape(nb, D)

@@ -3,6 +3,10 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
         --scale tiny --requests 8 --slots 4
 
+``--scale full`` serves the published configuration (random weights) on
+the chip JAX sees.  The run exits 1 if any request failed or fell short
+of its tokens, or a decode error or dropped task was recorded.
+
 Model-axis-sharded decode (vocab-parallel unembed) with the per-step
 logits all-gather either in-program (native) or as persistent user-space
 collectives on the serve-collective stream:
@@ -18,13 +22,14 @@ pressure) is the only cache layout — the fixed-slot path is retired:
         --slots 12 --kv-block-size 16 --kv-blocks 65 --requests 64
 """
 import argparse
+import dataclasses
 import os
 import sys
 
 import numpy as np
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--scale", default="tiny", choices=["tiny", "small", "full"])
@@ -32,6 +37,10 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--max-seq", type=int, default=128)
+    ap.add_argument("--prompt-len", type=int, nargs=2, default=(2, 7),
+                    metavar=("MIN", "MAX"),
+                    help="prompt lengths are drawn uniformly from "
+                         "[MIN, MAX]")
     ap.add_argument("--cache-mode", default="paged",
                     choices=["slots", "paged"],
                     help="KV cache layout; 'paged' (the only mode) is a "
@@ -49,7 +58,8 @@ def main():
                     help="fused prefill calls interleaved per admission "
                          "round before decode resumes (paged mode)")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host devices (CPU rehearsal)")
+                    help="CPU rehearsal on N forced host devices (selects "
+                         "the CPU platform)")
     ap.add_argument("--model-shards", type=int, default=0,
                     help="shard decode over a 'model' mesh axis of this "
                          "size (0 = unsharded)")
@@ -86,7 +96,31 @@ def main():
                          "epoch) and report the recovery")
     ap.add_argument("--stats", action="store_true",
                     help="print progress statistics after serving")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+@dataclasses.dataclass
+class Server:
+    """What ``build`` assembles: the model, the serve engine and the
+    progress machinery around it."""
+    cfg: object
+    srv: object
+    engine: object
+    executor: object = None
+    epoch: object = None
+    heartbeat: object = None
+
+
+def build(args: argparse.Namespace) -> Server:
+    """The configured model (random weights from PRNGKey(0)) behind a
+    ServeEngine, as the flags say."""
+    import jax
+
+    from repro.collectives.nonblocking import CollectiveSpec
+    from repro.configs.scales import scaled_config
+    from repro.core import ProgressEngine, ProgressExecutor
+    from repro.models import registry
+    from repro.serve.engine import ServeEngine
 
     if args.cache_mode == "slots":
         raise SystemExit(
@@ -95,43 +129,10 @@ def main():
             "fixed lanes with --kv-block-size B --kv-blocks "
             "(slots*max_seq//B + 1).")
 
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices} "
-            + os.environ.get("XLA_FLAGS", ""))
-
-    import jax
-
-    from repro.configs import get_config
-    from repro.core import ProgressEngine, ProgressExecutor
-    from repro.core import stats as stats_mod
-    from repro.models import registry
-    from repro.collectives.nonblocking import CollectiveSpec
-    from repro.serve.engine import GenRequest, ServeEngine
-    from examples.train_lm import SCALES
-
     spec = CollectiveSpec(backend=args.collective_backend,
                           chunks=args.collective_chunks,
                           round_batch=args.collective_round_batch or None)
-
-    cfg = get_config(args.arch)
-    overrides = dict(SCALES[args.scale])
-    if overrides:
-        if cfg.moe:
-            overrides["moe"] = cfg.moe.__class__(
-                num_experts=4, top_k=2, expert_d_ff=overrides["d_ff"] // 2,
-                group_size=64)
-        if cfg.ssm:
-            overrides["ssm"] = cfg.ssm.__class__(d_state=16, expand=2,
-                                                 head_dim=16, chunk_size=16)
-        if cfg.shared_attn_every:
-            overrides.update(num_layers=5, shared_attn_every=2,
-                             shared_attn_lora_rank=8)
-        if cfg.is_encoder_decoder:
-            overrides.update(num_encoder_layers=2, encoder_frames=16,
-                             max_position_embeddings=256)
-        cfg = cfg.with_overrides(**overrides)
-
+    cfg = scaled_config(args.arch, args.scale)
     params = registry.init_params(cfg, jax.random.PRNGKey(0))
     eng = ProgressEngine()
     executor = None
@@ -179,46 +180,92 @@ def main():
                       epoch=epoch)
     if executor is not None:
         executor.start()
-    rng = np.random.RandomState(1)
-    reqs = []
+    return Server(cfg, srv, eng, executor, epoch, heartbeat)
 
-    def make_request(i):
-        prompt = rng.randint(1, cfg.vocab_size - 1,
-                             size=rng.randint(2, 8)).astype(np.int32)
-        r = GenRequest(f"req{i}", prompt, max_new_tokens=args.max_new)
-        srv.submit(r)
-        reqs.append(r)
 
+def random_prompts(cfg, n: int, lengths, seed: int = 1) -> list[np.ndarray]:
+    """``n`` prompts of uniform random tokens, lengths uniform in
+    ``lengths`` = (min, max) inclusive."""
+    rng = np.random.RandomState(seed)
+    lo, hi = lengths
+    return [rng.randint(1, cfg.vocab_size - 1,
+                        size=rng.randint(lo, hi + 1)).astype(np.int32)
+            for _ in range(n)]
+
+
+def serve(server: Server, prompts, max_new: int, tag: str = "req",
+          timeout: float = 600) -> list:
+    """Submit one request per prompt and serve until idle; returns the
+    requests in submit order."""
+    from repro.serve.engine import GenRequest
+    reqs = [GenRequest(f"{tag}{i}", p, max_new_tokens=max_new)
+            for i, p in enumerate(prompts)]
+    for r in reqs:
+        server.srv.submit(r)
+    server.srv.run_until_idle(timeout=timeout)
+    return reqs
+
+
+def shortfalls(server: Server, reqs) -> list[str]:
+    """What went wrong while serving ``reqs``: recorded failures, and
+    requests that did not complete with all their tokens."""
+    out = [f"{type(e).__name__}: {e}" for e in server.srv.failures()]
+    for r in reqs:
+        if not r.done_req.is_complete or r.done_req.failed \
+                or len(r.out_tokens) != r.max_new_tokens:
+            out.append(f"{r.request_id}: {len(r.out_tokens)}/"
+                       f"{r.max_new_tokens} tokens, exception="
+                       f"{r.done_req.exception!r}")
+    return out
+
+
+def close(server: Server) -> None:
+    server.srv.close(timeout=60)
+    if server.executor is not None:
+        server.executor.shutdown(drain=True, timeout=60)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.devices:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.devices} "
+            + os.environ.get("XLA_FLAGS", ""))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
+    import jax
+
+    from repro.core import stats as stats_mod
+
+    server = build(args)
+    srv, eng, executor, epoch = (server.srv, server.engine, server.executor,
+                                 server.epoch)
+    prompts = random_prompts(server.cfg, args.requests, args.prompt_len)
     if args.chaos_kill > 0:
         import time as _time
         half = max(1, args.requests // 2)
-        for i in range(half):
-            make_request(i)
-        srv.run_until_idle(timeout=600)
+        reqs = serve(server, prompts[:half], args.max_new)
         survivors = max(1, len(jax.devices()) - args.chaos_kill)
         t_kill = _time.monotonic()
         epoch.invalidate(survivors=survivors,
                          reason=f"--chaos-kill {args.chaos_kill}")
-        for i in range(half, args.requests):
-            make_request(i)
-        srv.run_until_idle(timeout=600)
+        reqs += serve(server, prompts[half:], args.max_new, tag="late")
         t_rec = (_time.monotonic() - t_kill) * 1e3
         print(f"chaos: killed {args.chaos_kill} device(s) -> {survivors} "
               f"survivors; remeshes={srv.remeshes}, second half served "
               f"in {t_rec:.1f} ms")
     else:
-        for i in range(args.requests):
-            make_request(i)
-        srv.run_until_idle(timeout=600)
-    if heartbeat is not None:
-        for peer in heartbeat.alive:
-            heartbeat.beat(peer)
+        reqs = serve(server, prompts, args.max_new)
+    if server.heartbeat is not None:
+        for peer in server.heartbeat.alive:
+            server.heartbeat.beat(peer)
     snap = stats_mod.collect(eng, executor)   # before close drops the queue
     lat = srv.latency_snapshot()              # before close, too
     sched = srv.scheduler_snapshot()
-    srv.close(timeout=60)
-    if executor is not None:
-        executor.shutdown(drain=True, timeout=60)
+    problems = shortfalls(server, reqs)       # before close frees streams
+    close(server)
 
     gen = sum(len(r.out_tokens) for r in reqs)
     mode = (f"{args.progress_workers} progress workers"
@@ -236,7 +283,9 @@ def main():
         print(sched.format())
     if args.stats:
         print(stats_mod.format_stats(snap))
-    return 0
+    for p in problems:
+        print(f"FAILED {p}", file=sys.stderr)
+    return 1 if problems else 0
 
 
 if __name__ == "__main__":

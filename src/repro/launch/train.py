@@ -1,8 +1,8 @@
 """Production training launcher.
 
-On a real TPU fleet every host runs this same script (JAX SPMD runtime);
-on this CPU container use --devices to force host devices for a scaled
-rehearsal, e.g.:
+On a TPU host the script trains on the chips JAX sees (``--scale full``
+is the published configuration); ``--devices N`` is a CPU rehearsal on N
+forced host devices, e.g.:
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
         --devices 8 --mesh 4x2 --scale tiny --steps 20
@@ -11,15 +11,49 @@ All async subsystems (data prefetch, checkpointing, monitors) run on the
 one collated progress engine (see DESIGN.md).
 """
 import argparse
+import dataclasses
 import os
 import sys
 
 
-def main():
+@dataclasses.dataclass
+class TrainRun:
+    """What a training run reports: the trainer's logged metrics, the
+    final parameters (FSDP: the shard buckets), and how many of their
+    leaves the run changed."""
+    log: list
+    params: object
+    moved: int
+    leaves: int
+
+
+def _fingerprint(tree):
+    """Per-leaf (sum, sum of |x|) on the host: cheap evidence that an
+    update touched every leaf."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    def sums(t):
+        return jnp.stack([
+            jnp.stack([jnp.sum(l.astype(jnp.float32)),
+                       jnp.sum(jnp.abs(l.astype(jnp.float32)))])
+            for l in jax.tree.leaves(t)])
+
+    return np.asarray(jax.jit(sums)(tree))
+
+
+def _moved(before, after) -> tuple[int, int]:
+    import numpy as np
+    return int(np.sum(np.any(before != after, axis=1))), len(before)
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host devices (CPU rehearsal)")
+                    help="CPU rehearsal on N forced host devices (selects "
+                         "the CPU platform)")
     ap.add_argument("--mesh", default="", help="e.g. 4x2 -> (data=4, model=2)")
     ap.add_argument("--scale", default="tiny", choices=["tiny", "small", "full"])
     ap.add_argument("--steps", type=int, default=20)
@@ -83,21 +117,42 @@ def main():
                          "logged step >= --chaos-kill-step (requires "
                          "--elastic)")
     ap.add_argument("--chaos-kill-step", type=int, default=10)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
     if args.devices:
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", ""))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.pipeline != "none":
         return _run_pipeline(args)
+    result = run(args)
+    fmt = "{:.6f}" if args.fsdp else "{:.4f}"
+    if result.log:
+        print(f"final loss {fmt.format(result.log[-1]['loss'])}")
+        print(f"params moved: {result.moved}/{result.leaves} leaves")
+    else:
+        # resume found a checkpoint at/past --steps: nothing left to run
+        ckpt = os.path.join(args.ckpt_dir,
+                            args.arch + ("-fsdp" if args.fsdp else ""))
+        print(f"nothing to do: resumed past step {args.steps - 1} "
+              f"(rm -r {ckpt} to restart)")
+    return 0
 
+
+def run(args: argparse.Namespace) -> TrainRun:
+    """Train as the (parsed) flags say, on the devices JAX sees."""
     import jax
     import jax.numpy as jnp
 
     from repro import compat
-    from repro.configs import get_config
+    from repro.configs.scales import scaled_config
     from repro.configs.shapes import ShapeSpec
     from repro.core import ProgressEngine
     from repro.data.pipeline import PrefetchPipeline, SyntheticLM
@@ -106,7 +161,6 @@ def main():
     from repro.models import registry
     from repro.train import optimizer as opt_mod
     from repro.train.train_loop import Trainer, TrainLoopConfig
-    from examples.train_lm import SCALES  # reuse the reduction table
 
     n_dev = len(jax.devices())
     if args.mesh:
@@ -116,23 +170,7 @@ def main():
     mesh = make_mesh(shape, ("data", "model"))
     print(f"devices={n_dev} mesh={dict(mesh.shape)}")
 
-    cfg = get_config(args.arch)
-    overrides = dict(SCALES[args.scale])
-    if overrides:
-        if cfg.moe:
-            overrides["moe"] = cfg.moe.__class__(
-                num_experts=4, top_k=2, expert_d_ff=overrides["d_ff"] // 2,
-                group_size=64)
-        if cfg.ssm:
-            overrides["ssm"] = cfg.ssm.__class__(d_state=16, expand=2,
-                                                 head_dim=16, chunk_size=16)
-        if cfg.shared_attn_every:
-            overrides.update(num_layers=5, shared_attn_every=2,
-                             shared_attn_lora_rank=8)
-        if cfg.is_encoder_decoder:
-            overrides.update(num_encoder_layers=2, encoder_frames=16,
-                             max_position_embeddings=256)
-        cfg = cfg.with_overrides(**overrides)
+    cfg = scaled_config(args.arch, args.scale)
 
     shape_spec = ShapeSpec("train", seq_len=args.seq,
                            global_batch=args.global_batch, kind="train")
@@ -167,6 +205,7 @@ def main():
         params = jax.device_put(params, cell.in_shardings[0])
         opt_state = jax.device_put(opt_state, cell.in_shardings[1])
         b_shardings = cell.in_shardings[2]
+    before = _fingerprint(params)
 
     eng = ProgressEngine()
     src = SyntheticLM(cfg.vocab_size, args.seq, args.global_batch, seed=5)
@@ -308,13 +347,8 @@ def main():
     pipe.close()
     if reducer is not None:
         reducer.close()
-    if log:
-        print(f"final loss {log[-1]['loss']:.4f}")
-    else:
-        # resume found a checkpoint at/past --steps: nothing left to run
-        print(f"nothing to do: resumed past step {args.steps - 1} "
-              f"(rm -r {loop_cfg.checkpoint_dir} to restart)")
-    return 0
+    return TrainRun(log, trainer.params,
+                    *_moved(before, _fingerprint(trainer.params)))
 
 
 def build_fsdp_programs(cfg, ocfg, mesh, layout, *, axis="data"):
@@ -462,6 +496,7 @@ def _run_fsdp(args, cfg, ocfg, mesh):
         return layout, shards, opt_mod.AdamWState(step, mu, nu)
 
     layout, shards, opt_state = shard_state(mesh, params)
+    before = _fingerprint(shards)
     print(f"fsdp: {layout.num_buckets} bucket(s), shard widths "
           f"{[w // layout.n for w in layout.widths]} over {axis}="
           f"{layout.n} ({args.collective_backend} backend)")
@@ -548,12 +583,8 @@ def _run_fsdp(args, cfg, ocfg, mesh):
         print(f"prefetch overlap: {reducer.prefetch_overlap:.3f} "
               f"({reducer.gathers} chained gathers)")
         reducer.close()
-    if log:
-        print(f"final loss {log[-1]['loss']:.6f}")
-    else:
-        print(f"nothing to do: resumed past step {args.steps - 1} "
-              f"(rm -r {loop_cfg.checkpoint_dir} to restart)")
-    return 0
+    return TrainRun(log, trainer.params,
+                    *_moved(before, _fingerprint(trainer.params)))
 
 
 def _run_pipeline(args):

@@ -298,12 +298,22 @@ def attention_blockskip(q, k, v, *, chunk: int = 1024, logit_cap: float = 0.0):
     return out.astype(q.dtype)
 
 
+ATTENTION_IMPLS = ("xla", "xla_blockskip", "ring")
+
+
 def attention_dispatch(cfg, q, k, v, *, causal: bool = True):
-    """Select the attention implementation from cfg.attention_impl."""
-    if cfg.attention_impl == "ring" and causal:
+    """Select the attention implementation from cfg.attention_impl.
+
+    ``xla_blockskip`` and ``ring`` are causal schedules; non-causal
+    attention runs the plain chunked ``xla`` path under either."""
+    impl = cfg.attention_impl
+    if impl not in ATTENTION_IMPLS:
+        raise ValueError(f"attention_impl {impl!r} has no implementation; "
+                         f"choose one of {ATTENTION_IMPLS}")
+    if impl == "ring" and causal:
         from repro.collectives.ring_attention import ring_attention
         return ring_attention(q, k, v, causal=True, logit_cap=cfg.logit_softcap)
-    if cfg.attention_impl == "xla_blockskip" and causal:
+    if impl == "xla_blockskip" and causal:
         return attention_blockskip(q, k, v, chunk=cfg.attention_chunk,
                                    logit_cap=cfg.logit_softcap)
     return attention(q, k, v, causal=causal, chunk=cfg.attention_chunk,
@@ -469,16 +479,16 @@ def _moe_expert_block(xg, dispatch, combine, wi_gate, wi_up, wo):
     Expert weights enter as their (F @ model)-sharded local blocks; the
     d-dim FSDP gather happens once per layer at the shard_map boundary.
     """
-    from repro.sharding import _abstract_mesh, resolve_spec
-    mesh = _abstract_mesh()
+    from repro.sharding import resolve_spec
+    mesh = compat.current_mesh()
     F = wi_gate.shape[-1]
-    tp = 1 if (mesh is None or mesh.empty) else mesh.shape.get("model", 1)
+    tp = 1 if mesh.empty else mesh.shape.get("model", 1)
     # The explicit block imposes expert-internal TP (F over `model`).
     # Worth it only for wide experts (grok: F/tp = 2048); for many-tiny-
     # expert MoEs (granite: F/tp = 32) the [g,t,E,C] combine-space psums
     # exceed the savings — measured in EXPERIMENTS §Perf B — so fall back
     # to the capacity-sharded einsum formulation.
-    if mesh is None or mesh.empty or tp == 1 or F % tp != 0 \
+    if mesh.empty or tp == 1 or F % tp != 0 \
             or F // tp < 512 or not in_training():
         xe = jnp.einsum("gtec,gtd->gecd", dispatch, xg)
         xe = shard_hint(xe, "moe_groups", "act_experts", "expert_cap", "act_embed")

@@ -267,6 +267,11 @@ class ServeEngine:
         if prefill_chunk < 1:
             raise ValueError(f"prefill_chunk must be >= 1, got "
                              f"{prefill_chunk}")
+        if mesh is not None:
+            # the sharded decode replicates the weights over the mesh:
+            # place them there once, not on every step's call
+            params = jax.device_put(
+                params, jax.sharding.NamedSharding(mesh, P()))
         self.cfg = cfg
         self.params = params
         self.engine = engine
@@ -985,6 +990,15 @@ class ServeEngine:
             self._jit_decode = jax.jit(
                 lambda p, c, t, q, bt, fd: registry.decode_step_paged(
                     p, cfg, c, t, q, bt, fd))
+
+    def failures(self) -> list[BaseException]:
+        """Every failure recorded while serving: failed prefill chunks and
+        decode steps (``decode_errors``) and tasks dropped from the serve
+        streams.  Call before ``close``, which hands the streams back."""
+        out = list(self.decode_errors)
+        for stream in self._bridge_streams:
+            out.extend(stream.task_errors)
+        return out
 
     # -- latency accounting ------------------------------------------------
     def _record_locked(self, req: GenRequest, failed: bool) -> None:

@@ -20,6 +20,8 @@ from typing import Mapping, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import compat
+
 # ---------------------------------------------------------------------------
 # Rule tables
 # ---------------------------------------------------------------------------
@@ -93,13 +95,6 @@ def merged_rules(overrides: Mapping[str, Sequence[MeshAxes]] | None) -> Rules:
 # Resolution
 # ---------------------------------------------------------------------------
 
-def _mesh_axis_size(mesh: Mesh, axes: MeshAxes) -> int:
-    n = 1
-    for a in axes:
-        n *= mesh.shape[a]
-    return n
-
-
 def resolve_spec(
     logical_axes: Sequence[str | None],
     shape: Sequence[int],
@@ -111,6 +106,12 @@ def resolve_spec(
     Falls back to replication for any dim the preferred mesh axes do not
     divide, and never assigns the same mesh axis to two dims.
     """
+    return _resolve(logical_axes, shape, dict(mesh.shape), rules)
+
+
+def _resolve(logical_axes, shape, axis_sizes: Mapping[str, int],
+             rules: Rules | None) -> P:
+    """``resolve_spec`` over the mesh axes named in ``axis_sizes`` only."""
     rules = rules or current_rules()
     shape = tuple(getattr(shape, "shape", shape))
     assert len(logical_axes) == len(shape), (logical_axes, shape)
@@ -127,9 +128,11 @@ def resolve_spec(
         for cand in candidates:
             if any(a in used for a in cand):
                 continue
-            if any(a not in mesh.shape for a in cand):
+            if any(a not in axis_sizes for a in cand):
                 continue
-            size = _mesh_axis_size(mesh, cand)
+            size = 1
+            for a in cand:
+                size *= axis_sizes[a]
             if size == 1 or (dim % size == 0 and size > 1):
                 chosen = cand
                 break
@@ -154,24 +157,20 @@ def logical_sharding(
 
 
 def shard_hint(x: jax.Array, *logical_axes: str | None):
-    """Apply a with_sharding_constraint for logical axes, if a mesh is set.
+    """Apply a with_sharding_constraint for logical axes over the current
+    mesh's Auto axes.
 
-    Outside a ``jax.set_mesh`` context (e.g. plain CPU unit tests) this is
-    a no-op, so model code can be written once.
+    Outside a ``jax.set_mesh`` context (e.g. plain CPU unit tests), and
+    inside a ``shard_map`` body whose axes are all Manual, there is no
+    Auto axis to constrain and this is a no-op, so model code can be
+    written once.
     """
-    mesh = _abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = compat.current_mesh()
+    auto = {a: mesh.shape[a] for a in mesh.auto_axes}
+    if not auto:
         return x
-    spec = resolve_spec(logical_axes, x.shape, mesh)
+    spec = _resolve(logical_axes, x.shape, auto, None)
     return jax.lax.with_sharding_constraint(x, spec)
-
-
-def _abstract_mesh():
-    try:
-        from repro import compat
-        return compat.current_mesh()
-    except Exception:
-        return None
 
 
 # ---------------------------------------------------------------------------

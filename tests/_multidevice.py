@@ -23,6 +23,7 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"     # forced host devices: a CPU rehearsal
     proc = subprocess.run(
         [sys.executable, "-c", preamble + textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env)

@@ -1,0 +1,125 @@
+"""Compile the main path's Pallas kernels and the paged decode step for a
+described TPU v5e chip, at published widths.
+
+Nothing runs: the TPU compiler, which ships with jax here, compiles for a
+chip that is described and not attached.  A compile that passes is not a
+chip run, but it catches what interpret mode cannot — block shapes the
+chip's tiling refuses, more VMEM than a kernel may use, a step that does
+not fit the device.  The topology is described inside a fixture, so the
+TPU library is loaded only by the process that runs these tests.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.decode_attention import flash_decode
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rmsnorm import rmsnorm_bwd, rmsnorm_fwd
+from repro.kernels.ssd_scan import ssd_chunk
+from repro.models import registry
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no TPU compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the cache but cannot be
+    # read back without the chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, sharding, *shapes):
+    compiled = jax.jit(fn).lower(*_on(sharding, shapes)).compile()
+    return compiled.as_text()
+
+
+def _sds(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+QWEN = get_config("qwen2-0.5b")          # 14/2 heads of 64
+MAMBA = get_config("mamba2-1.3b")
+
+
+def test_flash_attention_compiles(one_chip):
+    hd = QWEN.resolved_head_dim()
+    hlo = _compile(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                   one_chip, _sds((1, 2048, QWEN.num_heads, hd)),
+                   _sds((1, 2048, QWEN.num_kv_heads, hd)),
+                   _sds((1, 2048, QWEN.num_kv_heads, hd)))
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_decode_compiles(one_chip):
+    hd = QWEN.resolved_head_dim()
+    hlo = _compile(flash_decode, one_chip,
+                   _sds((8, QWEN.num_heads, hd)),
+                   _sds((8, 2048, QWEN.num_kv_heads, hd)),
+                   _sds((8, 2048, QWEN.num_kv_heads, hd)),
+                   _sds((8,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_ssd_chunk_compiles(one_chip):
+    from repro.models.mamba import dims
+    _, nh, hp, ds = dims(MAMBA)
+    Q = MAMBA.ssm.chunk_size
+    assert (Q, nh, hp, ds) == (256, 64, 64, 128)
+    hlo = _compile(ssd_chunk, one_chip,
+                   _sds((4, Q, nh, hp)), _sds((4, Q, ds)), _sds((4, Q, ds)),
+                   _sds((4, Q, nh), jnp.float32), _sds((nh,), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_rmsnorm_compiles(one_chip, which):
+    D = QWEN.d_model
+    x = _sds((4096, D))
+    s = _sds((D,), jnp.float32)
+    if which == "fwd":
+        hlo = _compile(rmsnorm_fwd, one_chip, x, s)
+    else:
+        hlo = _compile(rmsnorm_bwd, one_chip, x, s, x)
+    assert "tpu_custom_call" in hlo
+
+
+def test_qwen2_paged_decode_step_compiles(one_chip):
+    """The serve engine's unsharded decode program at published widths:
+    8 lanes, a 2048-position lane capacity in blocks of 16."""
+    cfg = QWEN
+    assert (cfg.num_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size) == \
+        (24, 896, 4864, 151936)
+    lanes, max_blocks, bs = 8, 128, 16
+    params = jax.eval_shape(
+        lambda: registry.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: registry.init_paged_cache(
+        cfg, lanes, lanes * max_blocks + 1, bs))
+    compiled = jax.jit(
+        lambda p, c, t, q, bt, fd: registry.decode_step_paged(
+            p, cfg, c, t, q, bt, fd)
+    ).lower(*_on(one_chip, (
+        params, cache, _sds((lanes, 1), jnp.int32), _sds((lanes,), jnp.int32),
+        _sds((lanes, max_blocks), jnp.int32), _sds((lanes,), jnp.bool_)))
+    ).compile()
+    mem = compiled.memory_analysis()
+    # weights (f32) + the KV pool + temporaries fit one v5e's 16 GB
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 0 < used < 16e9
