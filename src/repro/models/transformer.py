@@ -72,24 +72,28 @@ def _remat(fn, cfg: ModelConfig):
 def _layer_fwd(cfg: ModelConfig, x, lp, positions):
     if cfg.remat_policy == "subblock":
         return _layer_fwd_subblock(cfg, x, lp, positions)
-    h = L.rmsnorm(x, lp["ln1"], cfg.rms_norm_eps)
-    q, k, v = L.attn_qkv(lp["attn"], h, positions, cfg)
-    if cfg.remat_policy == "attn_only":
-        # recompute ONLY the attention internals in backward: everything
-        # else (projections, MLP) keeps its residuals — removes the full
-        # forward recompute at ~3GB/device of extra saved activations.
-        attn_fn = jax.checkpoint(
-            lambda q_, k_, v_: L.attention_dispatch(cfg, q_, k_, v_, causal=True))
-        o = attn_fn(q, k, v)
-    else:
-        o = L.attention_dispatch(cfg, q, k, v, causal=True)
-    x = x + L.attn_out(lp["attn"], o)
-    h = L.rmsnorm(x, lp["ln2"], cfg.rms_norm_eps)
-    if cfg.moe is not None:
-        y, aux = L.moe_apply(lp["moe"], h, cfg)
-    else:
-        y, aux = L.mlp_apply(lp["mlp"], h), jnp.zeros((), jnp.float32)
-    x = x + y
+    with jax.named_scope("attention"):
+        h = L.rmsnorm(x, lp["ln1"], cfg.rms_norm_eps)
+        q, k, v = L.attn_qkv(lp["attn"], h, positions, cfg)
+        if cfg.remat_policy == "attn_only":
+            # recompute ONLY the attention internals in backward:
+            # everything else (projections, MLP) keeps its residuals —
+            # removes the full forward recompute at ~3GB/device of extra
+            # saved activations.
+            attn_fn = jax.checkpoint(
+                lambda q_, k_, v_: L.attention_dispatch(cfg, q_, k_, v_,
+                                                        causal=True))
+            o = attn_fn(q, k, v)
+        else:
+            o = L.attention_dispatch(cfg, q, k, v, causal=True)
+        x = x + L.attn_out(lp["attn"], o)
+    with jax.named_scope("mlp"):
+        h = L.rmsnorm(x, lp["ln2"], cfg.rms_norm_eps)
+        if cfg.moe is not None:
+            y, aux = L.moe_apply(lp["moe"], h, cfg)
+        else:
+            y, aux = L.mlp_apply(lp["mlp"], h), jnp.zeros((), jnp.float32)
+        x = x + y
     x = shard_hint(x, "batch", "act_seq", "act_embed")
     return x, aux
 
@@ -373,28 +377,34 @@ def _layer_decode_paged(cfg: ModelConfig, x, lp, kc, vc, pos, tables,
     """One decoded token through one layer against the paged pool.
     x: [B,1,D]; kc/vc: [num_blocks, bs, KVH, hd] (int8 with ks/vs scale
     pools when cfg.kv_cache_dtype == "int8"); tables: [B, max_blocks]."""
-    h = L.rmsnorm(x, lp["ln1"], cfg.rms_norm_eps)
-    q, k_new, v_new = L.attn_qkv(lp["attn"], h, pos[:, None], cfg)
-    if ks is not None:
-        kq, ksc = _quantize_kv(k_new[:, 0])
-        vq, vsc = _quantize_kv(v_new[:, 0])
-        kc, vc = paged_scatter(kc, vc, kq, vq, tables, pos)
-        ks, vs = paged_scatter(ks, vs, ksc, vsc, tables, pos)
-        k_use = (_paged_view(kc, tables).astype(jnp.float32)
-                 * _paged_view(ks, tables)).astype(cfg.dtype)
-        v_use = (_paged_view(vc, tables).astype(jnp.float32)
-                 * _paged_view(vs, tables)).astype(cfg.dtype)
-    else:
-        kc, vc = paged_scatter(kc, vc, k_new[:, 0], v_new[:, 0], tables, pos)
-        k_use = _paged_view(kc, tables)
-        v_use = _paged_view(vc, tables)
-    o = L.decode_attention(q, k_use, v_use, pos, logit_cap=cfg.logit_softcap)
-    x = x + L.attn_out(lp["attn"], o)
-    h = L.rmsnorm(x, lp["ln2"], cfg.rms_norm_eps)
-    if cfg.moe is not None:
-        y, _ = L.moe_apply(lp["moe"], h, cfg)
-    else:
-        y = L.mlp_apply(lp["mlp"], h)
+    with jax.named_scope("attention"):
+        h = L.rmsnorm(x, lp["ln1"], cfg.rms_norm_eps)
+        q, k_new, v_new = L.attn_qkv(lp["attn"], h, pos[:, None], cfg)
+    with jax.named_scope("paged_view"):
+        if ks is not None:
+            kq, ksc = _quantize_kv(k_new[:, 0])
+            vq, vsc = _quantize_kv(v_new[:, 0])
+            kc, vc = paged_scatter(kc, vc, kq, vq, tables, pos)
+            ks, vs = paged_scatter(ks, vs, ksc, vsc, tables, pos)
+            k_use = (_paged_view(kc, tables).astype(jnp.float32)
+                     * _paged_view(ks, tables)).astype(cfg.dtype)
+            v_use = (_paged_view(vc, tables).astype(jnp.float32)
+                     * _paged_view(vs, tables)).astype(cfg.dtype)
+        else:
+            kc, vc = paged_scatter(kc, vc, k_new[:, 0], v_new[:, 0], tables,
+                                   pos)
+            k_use = _paged_view(kc, tables)
+            v_use = _paged_view(vc, tables)
+    with jax.named_scope("attention"):
+        o = L.decode_attention(q, k_use, v_use, pos,
+                               logit_cap=cfg.logit_softcap)
+        x = x + L.attn_out(lp["attn"], o)
+    with jax.named_scope("mlp"):
+        h = L.rmsnorm(x, lp["ln2"], cfg.rms_norm_eps)
+        if cfg.moe is not None:
+            y, _ = L.moe_apply(lp["moe"], h, cfg)
+        else:
+            y = L.mlp_apply(lp["mlp"], h)
     return x + y, kc, vc, ks, vs
 
 
@@ -407,7 +417,8 @@ def decode_step_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
     mask ever exposes it."""
     x, new_cache = decode_hidden_paged(params, cfg, cache, tokens, pos,
                                        tables, fed)
-    return unembed(params, cfg, x), new_cache
+    with jax.named_scope("logits"):
+        return unembed(params, cfg, x), new_cache
 
 
 def decode_hidden_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
@@ -452,18 +463,21 @@ def loss_fn(params, cfg: ModelConfig, batch):
                                 vision_embeds=batch.get("vision_embeds"))
         if x.shape[1] != labels.shape[1]:        # VLM: loss on text positions
             x = x[:, -labels.shape[1]:]
-        if cfg.tie_embeddings:
-            nll = chunked_vocab_xent(x, params["embed"], labels,
-                                     cfg.loss_vocab_chunk, False)
-        else:
-            nll = chunked_vocab_xent(x, params["lm_head"], labels,
-                                     cfg.loss_vocab_chunk, True)
+        with jax.named_scope("logits"):
+            if cfg.tie_embeddings:
+                nll = chunked_vocab_xent(x, params["embed"], labels,
+                                         cfg.loss_vocab_chunk, False)
+            else:
+                nll = chunked_vocab_xent(x, params["lm_head"], labels,
+                                         cfg.loss_vocab_chunk, True)
         return nll + aux, {"nll": nll, "aux": aux}
-    logits, aux = forward(params, cfg, batch["tokens"],
-                          vision_embeds=batch.get("vision_embeds"))
-    if logits.shape[1] != labels.shape[1]:       # VLM: loss on text positions
-        logits = logits[:, -labels.shape[1]:]
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = jnp.mean(lse - gold)
+    x, aux = forward_hidden(params, cfg, batch["tokens"],
+                            vision_embeds=batch.get("vision_embeds"))
+    with jax.named_scope("logits"):
+        logits = unembed(params, cfg, x)
+        if logits.shape[1] != labels.shape[1]:   # VLM: loss on text positions
+            logits = logits[:, -labels.shape[1]:]
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        nll = jnp.mean(lse - gold)
     return nll + aux, {"nll": nll, "aux": aux}

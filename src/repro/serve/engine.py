@@ -121,6 +121,16 @@ class GenRequest:
     kv_ckpt: Optional[dict] = None
 
 
+def _serve_program(cfg):
+    """The unsharded serving program: one fused paged call that prefill
+    and decode both dispatch.  Jitted from a named function, so that the
+    device trace names its module ``jit_serve_call``."""
+    def serve_call(params, cache, tokens, pos, tables, fed):
+        return registry.decode_step_paged(params, cfg, cache, tokens, pos,
+                                          tables, fed)
+    return jax.jit(serve_call)
+
+
 class _BucketBacklog:
     """Length-bucketed FIFO backlog (power-of-two length buckets).
 
@@ -332,9 +342,7 @@ class ServeEngine:
             self.coll = None
             self._ag_handle = None
             self._jit_gather = None
-            self._jit_decode = jax.jit(
-                lambda p, c, t, q, bt, fd: registry.decode_step_paged(
-                    p, cfg, c, t, q, bt, fd))
+            self._jit_decode = _serve_program(cfg)
         self.admit_stream = engine.stream("serve-admit")
         self.decode_stream = engine.stream("serve-decode")
         # decode completions are delivered through this queue; its
@@ -368,6 +376,7 @@ class ServeEngine:
             self._sub = engine.register_subsystem(
                 "serve-streams", self._poll_streams, cheap=True, priority=4)
         self.steps = 0
+        self._calls = 0                # serving-program dispatches so far
         # bounded: transient device failures on a long-lived server must
         # not accumulate exception objects forever
         self.decode_errors: collections.deque[BaseException] = \
@@ -513,28 +522,9 @@ class ServeEngine:
         with self._lock:
             if self._decode_inflight is not None or self._prefill_active:
                 return False
-            now = time.monotonic()
-            while self._arrivals:
-                req = self._arrivals.popleft()
-                if req.replay is None:
-                    req.replay = np.asarray(req.prompt, np.int32)
-                self._backlog.push(req)
-
-            def fits(req):
-                return self.slots.assign(req.request_id,
-                                         seq_len=len(req.replay))
-
-            admitted = []
-            while self.slots.free_count:
-                req, lane = self._backlog.pop_fitting(fits)
-                if req is None:
-                    break
-                req.slot_index = lane.index
-                req.prefill_pos = 0
-                req.queued_s += now - req.last_enqueued_at
-                self._prefilling[lane.index] = req
-                admitted.append(req)
-                self.sched.admitted += 1
+            with jax.profiler.TraceAnnotation("serve.admit") as span:
+                admitted = self._admit_pass_locked()
+                span.set_metadata(admitted=len(admitted))
             if not self._prefilling:
                 return False
             self.sched.peak_resident = max(
@@ -578,6 +568,34 @@ class ServeEngine:
                 self._active[idx] = self._prefilling.pop(idx)
         return True
 
+    def _admit_pass_locked(self) -> list:
+        """Drain arrivals into the backlog and claim a lane (and its
+        prefill blocks) for every request that fits; caller holds
+        ``self._lock``.  Returns the requests admitted."""
+        now = time.monotonic()
+        while self._arrivals:
+            req = self._arrivals.popleft()
+            if req.replay is None:
+                req.replay = np.asarray(req.prompt, np.int32)
+            self._backlog.push(req)
+
+        def fits(req):
+            return self.slots.assign(req.request_id,
+                                     seq_len=len(req.replay))
+
+        admitted = []
+        while self.slots.free_count:
+            req, lane = self._backlog.pop_fitting(fits)
+            if req is None:
+                break
+            req.slot_index = lane.index
+            req.prefill_pos = 0
+            req.queued_s += now - req.last_enqueued_at
+            self._prefilling[lane.index] = req
+            admitted.append(req)
+            self.sched.admitted += 1
+        return admitted
+
     def _prefill_chunk(self, cache):
         """Up to ``prefill_chunk`` fused paged calls over the staged
         cache; logits are discarded (and in sharded mode no gather is
@@ -591,15 +609,19 @@ class ServeEngine:
                        if req.prefill_pos < len(req.replay) - 1]
             if not feeding:
                 break
-            toks = np.zeros((self.batch_slots, 1), np.int32)
-            fed = np.zeros((self.batch_slots,), bool)
-            for idx, req in feeding:
-                toks[idx, 0] = int(req.replay[req.prefill_pos])
-                fed[idx] = True
-            _, cache = self._jit_decode(
-                self.params, cache, jnp.asarray(toks),
-                self.slots.positions(), self.slots.block_tables(),
-                jnp.asarray(fed))
+            self._calls += 1
+            with jax.profiler.TraceAnnotation("serve.prefill",
+                                              call=self._calls,
+                                              lanes=len(feeding)):
+                toks = np.zeros((self.batch_slots, 1), np.int32)
+                fed = np.zeros((self.batch_slots,), bool)
+                for idx, req in feeding:
+                    toks[idx, 0] = int(req.replay[req.prefill_pos])
+                    fed[idx] = True
+                _, cache = self._jit_decode(
+                    self.params, cache, jnp.asarray(toks),
+                    self.slots.positions(), self.slots.block_tables(),
+                    jnp.asarray(fed))
             for idx, req in feeding:
                 req.prefill_pos += 1
                 self.slots.slots[idx].pos += 1
@@ -659,23 +681,30 @@ class ServeEngine:
         """
         step = Request(tag="decode-step")
         self._current_step = step
+        self._calls += 1
         try:
-            self._ensure_capacity_locked()
-            toks = np.zeros((self.batch_slots, 1), np.int32)
-            for idx, req in self._active.items():
-                toks[idx, 0] = req.next_input
-            pos = self.slots.positions()
-            fed = np.zeros((self.batch_slots,), bool)
-            for idx in self._active:
-                fed[idx] = True
-            out, cache = self._jit_decode(
-                self.params, self.slots.cache, jnp.asarray(toks), pos,
-                self.slots.block_tables(), jnp.asarray(fed))
-            if self._jit_gather is not None:     # native-sharded gather
-                out = self._jit_gather(out)
-            agreq = None
-            if self._ag_handle is not None:      # user-space gather
-                agreq = self._ag_handle.start(out)
+            with jax.profiler.TraceAnnotation("serve.decode",
+                                              call=self._calls,
+                                              step=self.steps + 1) as span:
+                self._ensure_capacity_locked()
+                lanes = len(self._active)
+                span.set_metadata(lanes=lanes)
+                toks = np.zeros((self.batch_slots, 1), np.int32)
+                for idx, req in self._active.items():
+                    toks[idx, 0] = req.next_input
+                pos = self.slots.positions()
+                fed = np.zeros((self.batch_slots,), bool)
+                for idx in self._active:
+                    fed[idx] = True
+                out, cache = self._jit_decode(
+                    self.params, self.slots.cache, jnp.asarray(toks), pos,
+                    self.slots.block_tables(), jnp.asarray(fed))
+                if self._jit_gather is not None:     # native-sharded gather
+                    out = self._jit_gather(out)
+                agreq = None
+                if self._ag_handle is not None:      # user-space gather
+                    agreq = self._ag_handle.start(out)
+            self.sched.decode_lanes += lanes
         except BaseException as exc:  # noqa: BLE001
             step.fail(exc)
             return step, None, None
@@ -768,50 +797,53 @@ class ServeEngine:
     def _on_step_done(self, step: Request) -> None:
         """Detokenize stage (a continuation): harvest the fused step,
         finish/complete requests, and chain the next decode step."""
-        logits, cache = step.value()
-        try:
-            # materialize OUTSIDE the lock: this is where async device
-            # errors surface (not at dispatch) — a raise here must take
-            # the failure path, not wedge the server with _active full
-            # and no task on any stream
-            next_ids = self._next_ids(logits)
-        except BaseException as exc:  # noqa: BLE001
-            self._fail_step(step, exc)
-            return
-        freed = False
-        with self._lock:
-            if self._current_step is not step:
-                return                         # stale: a newer step owns state
-            self._current_step = None
-            self._decode_inflight = None
-            self.slots.cache = cache
-            self.steps += 1
-            finished = []
-            for idx, req in list(self._active.items()):
-                tok = int(next_ids[idx])
-                if req.first_token_at is None:
-                    # TTFT stamp: exactly once, on the first produced token
-                    req.first_token_at = time.monotonic()
-                req.out_tokens.append(tok)
-                req.next_input = tok
-                self.slots.slots[idx].pos += 1
-                if (len(req.out_tokens) >= req.max_new_tokens
-                        or self.slots.slots[idx].pos >= self.max_seq - 1):
-                    finished.append(idx)
-            for idx in finished:
-                req = self._active.pop(idx)
-                req.finished_at = time.monotonic()
-                self.slots.release(self.slots.slots[idx])
-                self._record_locked(req, failed=False)
-                req.done_req.complete(req.out_tokens)
-                freed = True
-        # admit between steps: arrivals that landed while this step was
-        # in flight (their admission was deferred — prefill and an
-        # in-flight step must not both write slots.cache) join the batch
-        # before the next launch.  Prefill stages outside the lock, so
-        # releasing it first keeps submit() responsive during admission.
-        self._admit()
-        self._schedule_decode()                # chain the next step
+        n = self.steps + 1                     # the step this harvests
+        with jax.profiler.TraceAnnotation("serve.harvest", step=n):
+            logits, cache = step.value()
+            try:
+                # materialize OUTSIDE the lock: this is where async device
+                # errors surface (not at dispatch) — a raise here must take
+                # the failure path, not wedge the server with _active full
+                # and no task on any stream
+                with jax.profiler.TraceAnnotation("serve.sample", step=n):
+                    next_ids = self._next_ids(logits)
+            except BaseException as exc:  # noqa: BLE001
+                self._fail_step(step, exc)
+                return
+            freed = False
+            with self._lock:
+                if self._current_step is not step:
+                    return                     # stale: a newer step owns state
+                self._current_step = None
+                self._decode_inflight = None
+                self.slots.cache = cache
+                self.steps += 1
+                finished = []
+                for idx, req in list(self._active.items()):
+                    tok = int(next_ids[idx])
+                    if req.first_token_at is None:
+                        # TTFT stamp: exactly once, on the first token
+                        req.first_token_at = time.monotonic()
+                    req.out_tokens.append(tok)
+                    req.next_input = tok
+                    self.slots.slots[idx].pos += 1
+                    if (len(req.out_tokens) >= req.max_new_tokens
+                            or self.slots.slots[idx].pos >= self.max_seq - 1):
+                        finished.append(idx)
+                for idx in finished:
+                    req = self._active.pop(idx)
+                    req.finished_at = time.monotonic()
+                    self.slots.release(self.slots.slots[idx])
+                    self._record_locked(req, failed=False)
+                    req.done_req.complete(req.out_tokens)
+                    freed = True
+            # admit between steps: arrivals that landed while this step was
+            # in flight (their admission was deferred — prefill and an
+            # in-flight step must not both write slots.cache) join the batch
+            # before the next launch.  Prefill stages outside the lock, so
+            # releasing it first keeps submit() responsive during admission.
+            self._admit()
+            self._schedule_decode()            # chain the next step
         if freed:
             self._schedule_admit()             # the slot-free event
 
@@ -985,11 +1017,8 @@ class ServeEngine:
                 self._bridge_streams = [self.admit_stream,
                                         self.decode_stream, self.coll.stream]
         else:
-            cfg = self.cfg
             self._jit_gather = None
-            self._jit_decode = jax.jit(
-                lambda p, c, t, q, bt, fd: registry.decode_step_paged(
-                    p, cfg, c, t, q, bt, fd))
+            self._jit_decode = _serve_program(self.cfg)
 
     def failures(self) -> list[BaseException]:
         """Every failure recorded while serving: failed prefill chunks and

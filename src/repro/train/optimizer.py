@@ -126,6 +126,7 @@ def apply_shards(cfg: AdamWConfig, state: AdamWState, shards, grad_shards,
     return new_shards, new_state, {"grad_norm": gnorm, "lr": lr}
 
 
+@jax.named_scope("adamw")
 def apply(cfg: AdamWConfig, state: AdamWState, params, grads):
     """One AdamW step. Returns (new_params, new_state, metrics)."""
     gnorm = global_norm(grads)

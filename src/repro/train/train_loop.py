@@ -283,12 +283,25 @@ class Trainer:
 
     def _run_loop(self) -> list[dict]:
         self.maybe_resume()
-        loss_req: Request | None = None
-        metrics = None
         for step in range(self.start_step, self.cfg.total_steps):
+            with jax.profiler.StepTraceAnnotation("train.step",
+                                                  step_num=step):
+                self._run_step(step)
+        # finalize: drain pending checkpoint I/O (paper Listing 1.2 note:
+        # finalize spins progress until all async tasks complete)
+        if self._pending_ckpt is not None:
+            self.engine.wait(self._pending_ckpt, timeout=600)
+        return self.metrics_log
+
+    def _run_step(self, step: int) -> None:
+        """One iteration of the loop: fetch, dispatch, wait, log.  Each
+        part is a profiler span (``train.*``) on the device trace's
+        clock."""
+        with jax.profiler.TraceAnnotation("train.batch", step=step):
             batch = self.pipeline.next_batch()     # warm path: no block
-            t0 = time.monotonic()
-            self.watchdog.arm()
+        t0 = time.monotonic()
+        self.watchdog.arm()
+        with jax.profiler.TraceAnnotation("train.dispatch", step=step):
             if self.split_step is not None:
                 # engine-driven collective backend: dispatch local grads,
                 # issue the nonblocking bucketed allreduce, and let the
@@ -317,32 +330,29 @@ class Trainer:
                 # nonblocking dispatch — jit returns before device finishes
                 self.params, self.opt_state, metrics = self.step_fn(
                     self.params, self.opt_state, batch)
-            loss_req = jax_future(self.engine, metrics)
+        loss_req = jax_future(self.engine, metrics)
 
-            # overlap window: drive collated progress until device done
-            # (with progress workers attached, wait yields to them instead)
+        # overlap window: drive collated progress until device done
+        # (with progress workers attached, wait yields to them instead)
+        with jax.profiler.TraceAnnotation("train.wait", step=step):
             self.engine.wait(loss_req)
-            self.watchdog.disarm()
-            dur = time.monotonic() - t0
-            self.straggler.record("self", dur)
+        self.watchdog.disarm()
+        dur = time.monotonic() - t0
+        self.straggler.record("self", dur)
 
-            if (step + 1) % self.cfg.checkpoint_every == 0 \
-                    or step == self.cfg.total_steps - 1:
-                # async save: stages progress inside future loop iterations
-                self._pending_ckpt = self.ckpt.save_async(
-                    step, {"params": self.params, "opt_state": self.opt_state})
+        if (step + 1) % self.cfg.checkpoint_every == 0 \
+                or step == self.cfg.total_steps - 1:
+            # async save: stages progress inside future loop iterations
+            self._pending_ckpt = self.ckpt.save_async(
+                step, {"params": self.params, "opt_state": self.opt_state})
 
-            if step % self.cfg.log_every == 0 or step == self.cfg.total_steps - 1:
+        if step % self.cfg.log_every == 0 or step == self.cfg.total_steps - 1:
+            with jax.profiler.TraceAnnotation("train.log", step=step):
                 m = {k: float(np.asarray(v)) for k, v in metrics.items()}
                 m["step"] = step
                 m["step_time_s"] = dur
                 self.metrics_log.append(m)
                 for hook in self.hooks:
                     hook(step, m)
-            if self._hung:
-                raise RuntimeError("watchdog: step exceeded wall-clock limit")
-        # finalize: drain pending checkpoint I/O (paper Listing 1.2 note:
-        # finalize spins progress until all async tasks complete)
-        if self._pending_ckpt is not None:
-            self.engine.wait(self._pending_ckpt, timeout=600)
-        return self.metrics_log
+        if self._hung:
+            raise RuntimeError("watchdog: step exceeded wall-clock limit")
