@@ -259,16 +259,14 @@ PAGED_HAS_BLOCKS = True     # the attention sites cache KV per position
 
 def paged_cache_spec(cfg: ModelConfig, lanes: int, num_blocks: int,
                      block_size: int):
+    from repro.models.transformer import paged_pool_spec
     n_groups, k, tail = group_layout(cfg)
-    KVH, hd = cfg.num_kv_heads, cfg.resolved_head_dim()
-    kv_shape = (n_groups, num_blocks, block_size, KVH, hd)
-    kv_axes = ("layers", None, "cache_seq", "act_kv_heads", "head_dim")
+    kv = paged_pool_spec(cfg, n_groups, num_blocks, block_size,
+                         cfg.resolved_head_dim(), jnp.dtype(cfg.dtype))
     spec = {
         "ssm": M.state_spec(cfg, cfg.num_layers - tail, lanes),
-        "attn_k": L.PSpec(kv_shape, kv_axes, init="zeros",
-                          dtype=jnp.dtype(cfg.dtype)),
-        "attn_v": L.PSpec(kv_shape, kv_axes, init="zeros",
-                          dtype=jnp.dtype(cfg.dtype)),
+        "attn_k": kv,
+        "attn_v": kv,
     }
     if tail:
         spec["tail_ssm"] = M.state_spec(cfg, tail, lanes)
@@ -303,15 +301,15 @@ def reset_paged_lane(cfg: ModelConfig, cache, lane_index: int):
 
 def _shared_attn_paged(cfg, sp, lora, x, kc, vc, pos, tables):
     """Shared attention + MLP block against the paged KV pool of one
-    site.  kc/vc: [num_blocks, bs, KVH, hd]; tables: [B, max_blocks]."""
+    site.  kc/vc: [num_blocks, bs, KVH*hd]; tables: [B, max_blocks]."""
     from repro.models.transformer import _paged_view, paged_scatter
     ap = dict(sp["attn"])
     ap.update(lora)
     h = L.rmsnorm(x, sp["ln1"], cfg.rms_norm_eps)
     q, k, v = L.attn_qkv(ap, h, pos[:, None], cfg)
     kc, vc = paged_scatter(kc, vc, k[:, 0], v[:, 0], tables, pos)
-    o = L.decode_attention(q, _paged_view(kc, tables),
-                           _paged_view(vc, tables), pos)
+    o = L.decode_attention_merged(q, _paged_view(kc, tables),
+                                  _paged_view(vc, tables), pos)
     x = x + L.attn_out(ap, o)
     h = L.rmsnorm(x, sp["ln2"], cfg.rms_norm_eps)
     x = x + L.mlp_apply(sp["mlp"], h)

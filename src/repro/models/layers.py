@@ -346,6 +346,41 @@ def decode_attention(q, k_cache, v_cache, pos, *, logit_cap: float = 0.0):
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
+def decode_attention_merged(q, k_view, v_view, pos, *,
+                            logit_cap: float = 0.0):
+    """``decode_attention`` against views whose KV heads and head_dim
+    share one minor dimension, as the paged pool stores them.
+
+    q: [B, 1, H, hd]; views: [B, S, KVH*hd]; pos: [B] (#valid entries).
+    Each KV head's query group contracts with that head's columns of the
+    view, so the view is read in its stored layout and never split into
+    (KVH, hd); each head's products and sums are those of
+    ``decode_attention``, so the two agree bit for bit.
+    """
+    B, _, H, hd = q.shape
+    _, S, W = k_view.shape
+    KVH = W // hd
+    G = H // KVH
+    scale = 1.0 / math.sqrt(hd)
+    qg = q.reshape(B, KVH, G, hd).astype(jnp.float32) * scale
+    cols = [slice(h * hd, (h + 1) * hd) for h in range(KVH)]
+    s = jnp.stack([jnp.einsum("bgd,bsd->bgs", qg[:, h],
+                              k_view[:, :, c].astype(jnp.float32),
+                              preferred_element_type=jnp.float32)
+                   for h, c in enumerate(cols)], axis=1)     # [B,KVH,G,S]
+    s = softcap(s, logit_cap) if logit_cap else s
+    valid = jnp.arange(S)[None, :] < pos[:, None] + 1       # [B,S]
+    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    p = (p / l).astype(v_view.dtype)
+    out = jnp.stack([jnp.einsum("bgs,bsd->bgd", p[:, h], v_view[:, :, c],
+                                preferred_element_type=jnp.float32)
+                     for h, c in enumerate(cols)], axis=1)   # [B,KVH,G,hd]
+    return out.reshape(B, 1, H, hd).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Attention block (params + apply), GQA + optional bias + RoPE
 # ---------------------------------------------------------------------------

@@ -310,34 +310,42 @@ def decode_hidden(params, cfg: ModelConfig, cache, tokens, pos, fed=None):
 # lane carries a block *table* [max_blocks] of physical pool indices.
 # Per step, the new token's K/V is scattered into (table[pos//bs],
 # pos%bs) and attention runs over the table-gathered view
-# [B, max_blocks*block_size, KVH, hd] through the SAME masked
-# decode_attention as the monolithic path — positions > pos are masked
-# to exact zeros, so stale bytes in recycled blocks (and the shared
-# scratch block 0 behind unallocated table entries) are unreachable and
-# the gathered view is value-identical to a monolithic cache row.
+# [B, max_blocks*block_size, KVH*hd] — the same masked attention as the
+# monolithic path, with positions > pos masked to exact zeros, so stale
+# bytes in recycled blocks (and the shared scratch block 0 behind
+# unallocated table entries) are unreachable and the logits are
+# bit-identical to a monolithic cache row's.
+#
+# Every block-pooled leaf is [layers, num_blocks, block_size, KVH*width]:
+# heads and head_dim share one minor dimension (int8 scale pools have
+# width 1).  A trailing (KVH, hd) pair such as (2, 64) pads 8x under the
+# TPU's (8, 128) tiles, which leads the compiler to put the blocks
+# dimension minor and relay out every layer's slice around the scatter;
+# merged, the layout follows the shape and the blocks stay major.
 
 PAGED_HAS_BLOCKS = True     # per-position KV: sequences occupy pool blocks
 
 
+def paged_pool_spec(cfg: ModelConfig, layers: int, num_blocks: int,
+                    block_size: int, width: int, dtype):
+    """One block-pooled leaf: [layers, num_blocks, block_size, KVH*width]."""
+    return L.PSpec((layers, num_blocks, block_size,
+                    cfg.num_kv_heads * width),
+                   ("layers", None, "cache_seq", "act_kv_heads"),
+                   init="zeros", dtype=dtype)
+
+
 def paged_cache_spec(cfg: ModelConfig, lanes: int, num_blocks: int,
                      block_size: int):
-    NL, KVH = cfg.num_layers, cfg.num_kv_heads
-    hd = cfg.resolved_head_dim()
-    axes = ("layers", None, "cache_seq", "act_kv_heads", "head_dim")
-    shape = (NL, num_blocks, block_size, KVH, hd)
+    NL, hd = cfg.num_layers, cfg.resolved_head_dim()
     if cfg.kv_cache_dtype == "int8":
-        s_axes = ("layers", None, "cache_seq", "act_kv_heads", None)
-        s_shape = (NL, num_blocks, block_size, KVH, 1)
-        return {
-            "k": L.PSpec(shape, axes, init="zeros", dtype=jnp.int8),
-            "v": L.PSpec(shape, axes, init="zeros", dtype=jnp.int8),
-            "k_scale": L.PSpec(s_shape, s_axes, init="zeros", dtype=jnp.float32),
-            "v_scale": L.PSpec(s_shape, s_axes, init="zeros", dtype=jnp.float32),
-        }
-    return {
-        "k": L.PSpec(shape, axes, init="zeros", dtype=jnp.dtype(cfg.dtype)),
-        "v": L.PSpec(shape, axes, init="zeros", dtype=jnp.dtype(cfg.dtype)),
-    }
+        kv = paged_pool_spec(cfg, NL, num_blocks, block_size, hd, jnp.int8)
+        sc = paged_pool_spec(cfg, NL, num_blocks, block_size, 1,
+                             jnp.float32)
+        return {"k": kv, "v": kv, "k_scale": sc, "v_scale": sc}
+    kv = paged_pool_spec(cfg, NL, num_blocks, block_size, hd,
+                         jnp.dtype(cfg.dtype))
+    return {"k": kv, "v": kv}
 
 
 def init_paged_cache(cfg: ModelConfig, lanes: int, num_blocks: int,
@@ -353,30 +361,43 @@ def reset_paged_lane(cfg: ModelConfig, cache, lane_index: int):
 
 
 def paged_scatter(kc, vc, k_new, v_new, tables, pos):
-    """Scatter one token's K/V [B, KVH, hd] into the pool at
-    (table[pos//bs], pos%bs).  Lanes whose table entry is the scratch
-    block (idle lanes) land at physical block 0 — never gathered by a
-    live table, so the duplicate writes are harmless."""
+    """Scatter one token's K/V [B, KVH, width] into the pools
+    [num_blocks, bs, KVH*width] at (table[pos//bs], pos%bs).  Lanes
+    whose table entry is the scratch block (idle lanes) land at physical
+    block 0 — never gathered by a live table, so the duplicate writes
+    are harmless."""
     B = k_new.shape[0]
     bs = kc.shape[1]
     phys = tables[jnp.arange(B), pos // bs]
     off = pos % bs
-    return kc.at[phys, off].set(k_new), vc.at[phys, off].set(v_new)
+    return (kc.at[phys, off].set(k_new.reshape(B, -1)),
+            vc.at[phys, off].set(v_new.reshape(B, -1)))
 
 
 def _paged_view(pool, tables):
-    """Gather [num_blocks, bs, ...] through tables [B, max_blocks] into
-    the per-lane contiguous view [B, max_blocks*bs, ...]."""
+    """Gather [num_blocks, bs, X] through tables [B, max_blocks] into
+    the per-lane contiguous view [B, max_blocks*bs, X]."""
     B, nb = tables.shape
     v = pool[tables]
     return v.reshape((B, nb * v.shape[2]) + v.shape[3:])
 
 
+def _dequant_view(cfg: ModelConfig, view, scale):
+    """int8 view [B, S, KVH*hd] times its scales [B, S, KVH], in the
+    view's merged layout."""
+    B, S, W = view.shape
+    KVH = scale.shape[-1]
+    full = view.reshape(B, S, KVH, W // KVH).astype(jnp.float32) \
+        * scale[..., None]
+    return full.reshape(B, S, W).astype(cfg.dtype)
+
+
 def _layer_decode_paged(cfg: ModelConfig, x, lp, kc, vc, pos, tables,
                         ks=None, vs=None):
     """One decoded token through one layer against the paged pool.
-    x: [B,1,D]; kc/vc: [num_blocks, bs, KVH, hd] (int8 with ks/vs scale
-    pools when cfg.kv_cache_dtype == "int8"); tables: [B, max_blocks]."""
+    x: [B,1,D]; kc/vc: [num_blocks, bs, KVH*hd] (int8 with ks/vs scale
+    pools [num_blocks, bs, KVH] when cfg.kv_cache_dtype == "int8");
+    tables: [B, max_blocks]."""
     with jax.named_scope("attention"):
         h = L.rmsnorm(x, lp["ln1"], cfg.rms_norm_eps)
         q, k_new, v_new = L.attn_qkv(lp["attn"], h, pos[:, None], cfg)
@@ -386,18 +407,18 @@ def _layer_decode_paged(cfg: ModelConfig, x, lp, kc, vc, pos, tables,
             vq, vsc = _quantize_kv(v_new[:, 0])
             kc, vc = paged_scatter(kc, vc, kq, vq, tables, pos)
             ks, vs = paged_scatter(ks, vs, ksc, vsc, tables, pos)
-            k_use = (_paged_view(kc, tables).astype(jnp.float32)
-                     * _paged_view(ks, tables)).astype(cfg.dtype)
-            v_use = (_paged_view(vc, tables).astype(jnp.float32)
-                     * _paged_view(vs, tables)).astype(cfg.dtype)
+            k_use = _dequant_view(cfg, _paged_view(kc, tables),
+                                  _paged_view(ks, tables))
+            v_use = _dequant_view(cfg, _paged_view(vc, tables),
+                                  _paged_view(vs, tables))
         else:
             kc, vc = paged_scatter(kc, vc, k_new[:, 0], v_new[:, 0], tables,
                                    pos)
             k_use = _paged_view(kc, tables)
             v_use = _paged_view(vc, tables)
     with jax.named_scope("attention"):
-        o = L.decode_attention(q, k_use, v_use, pos,
-                               logit_cap=cfg.logit_softcap)
+        o = L.decode_attention_merged(q, k_use, v_use, pos,
+                                      logit_cap=cfg.logit_softcap)
         x = x + L.attn_out(lp["attn"], o)
     with jax.named_scope("mlp"):
         h = L.rmsnorm(x, lp["ln2"], cfg.rms_norm_eps)

@@ -124,7 +124,10 @@ class GenRequest:
 def _serve_program(cfg):
     """The unsharded serving program: one fused paged call that prefill
     and decode both dispatch.  Jitted from a named function, so that the
-    device trace names its module ``jit_serve_call``."""
+    device trace names its module ``jit_serve_call``.  ``cache`` is the
+    block pool, leaves ``[layers, num_blocks, block_size, KVH*hd]``
+    (``serve/kvcache.py``); it is not donated, so the pre-call pool
+    stays valid for a failed step or prefill chunk."""
     def serve_call(params, cache, tokens, pos, tables, fed):
         return registry.decode_step_paged(params, cfg, cache, tokens, pos,
                                           tables, fed)

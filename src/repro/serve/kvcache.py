@@ -14,6 +14,12 @@ Two cache disciplines share one lane-oriented interface (``slots``,
   requests; the serve engine preempts under block pressure instead of
   rejecting at admission.
 
+Every block-pooled leaf is ``[layers, num_blocks, block_size,
+KVH*width]``: the KV heads and their width (head_dim, or 1 for the int8
+scale pools) share one minor dimension, so the device layout keeps the
+blocks dimension major and the decode layer loop reads and writes each
+layer's slice as stored (``models/transformer.py``).
+
 Physical block 0 is reserved as a scratch block: idle decode lanes point
 their whole table at it, so the fused decode step's unconditional
 scatter-at-``pos`` lands somewhere harmless.  The masked decode
@@ -22,9 +28,9 @@ freshly extended block is only ever read at offsets that were just
 written — stale bytes in recycled blocks are unreachable.
 
 With a ``mesh`` the pool is placed replicated across the mesh devices at
-init (model-axis-sharded serving): every decode step donates and returns
-the pool in place, keeping the steady state free of per-step host→device
-transfers and resharding.
+init (model-axis-sharded serving): every decode step returns the pool
+replicated as it came, keeping the steady state free of per-step
+host→device transfers and resharding.
 """
 from __future__ import annotations
 
@@ -372,7 +378,8 @@ class PagedKVCache:
     # -- per-lane checkpoint / restore (KV migration) ----------------------
     # Leaf classification is by shape against the pool geometry: a leaf
     # whose dims 1/2 are (num_blocks, block_size) is block-pooled KV
-    # (transformer k/v + scales, hybrid attn_k/attn_v); a leaf whose dim 1
+    # ([layers, num_blocks, block_size, KVH*width]: transformer k/v +
+    # scales, hybrid attn_k/attn_v); a leaf whose dim 1
     # is the lane count is lane-indexed recurrent state (mamba/hybrid
     # ssm).  Block leaves are checked first so a coincidental
     # lanes == num_blocks match cannot misfile pooled KV.

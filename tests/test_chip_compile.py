@@ -8,6 +8,8 @@ chip's tiling refuses, more VMEM than a kernel may use, a step that does
 not fit the device.  The topology is described inside a fixture, so the
 TPU library is loaded only by the process that runs these tests.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -52,6 +54,32 @@ def _compile(fn, sharding, *shapes):
 
 def _sds(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _compile_paged_step(cfg, sharding, lanes, max_blocks, bs):
+    """The serve engine's unsharded serving program, compiled for
+    ``lanes`` lanes of ``max_blocks`` blocks of ``bs`` positions over a
+    pool of ``lanes * max_blocks + 1`` blocks."""
+    params = jax.eval_shape(
+        lambda: registry.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: registry.init_paged_cache(
+        cfg, lanes, lanes * max_blocks + 1, bs))
+    return jax.jit(
+        lambda p, c, t, q, bt, fd: registry.decode_step_paged(
+            p, cfg, c, t, q, bt, fd)
+    ).lower(*_on(sharding, (
+        params, cache, _sds((lanes, 1), jnp.int32), _sds((lanes,), jnp.int32),
+        _sds((lanes, max_blocks), jnp.int32), _sds((lanes,), jnp.bool_)))
+    ).compile()
+
+
+def _loop_bodies(hlo: str) -> list[str]:
+    """The text of every while loop's body computation in ``hlo``."""
+    bodies = []
+    for name in set(re.findall(r"\bbody=%([\w.\-]+)", hlo)):
+        head = re.search(rf"^%{re.escape(name)} ", hlo, re.MULTILINE)
+        bodies.append(hlo[head.start():hlo.index("\n}", head.start())])
+    return bodies
 
 
 QWEN = get_config("qwen2-0.5b")          # 14/2 heads of 64
@@ -106,20 +134,30 @@ def test_qwen2_paged_decode_step_compiles(one_chip):
     cfg = QWEN
     assert (cfg.num_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size) == \
         (24, 896, 4864, 151936)
-    lanes, max_blocks, bs = 8, 128, 16
-    params = jax.eval_shape(
-        lambda: registry.init_params(cfg, jax.random.PRNGKey(0)))
-    cache = jax.eval_shape(lambda: registry.init_paged_cache(
-        cfg, lanes, lanes * max_blocks + 1, bs))
-    compiled = jax.jit(
-        lambda p, c, t, q, bt, fd: registry.decode_step_paged(
-            p, cfg, c, t, q, bt, fd)
-    ).lower(*_on(one_chip, (
-        params, cache, _sds((lanes, 1), jnp.int32), _sds((lanes,), jnp.int32),
-        _sds((lanes, max_blocks), jnp.int32), _sds((lanes,), jnp.bool_)))
-    ).compile()
+    compiled = _compile_paged_step(cfg, one_chip, 8, 128, 16)
     mem = compiled.memory_analysis()
     # weights (f32) + the KV pool + temporaries fit one v5e's 16 GB
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < 16e9
+
+
+def test_qwen2_paged_pool_is_not_relaid_out_per_layer(one_chip):
+    """At the serving benchmark's shapes (64 lanes of 161 blocks of 16,
+    a pool of 10,305 blocks) the pool enters with its blocks dimension
+    major, and the layer loop copies no per-layer pool slice.  A pool
+    stored as [.., KVH, hd] = [.., 2, 64] took a blocks-minor layout, and
+    each layer relaid out its K and V slices four times."""
+    lanes, max_blocks, bs = 64, 161, 16
+    num_blocks = lanes * max_blocks + 1
+    compiled = _compile_paged_step(QWEN, one_chip, lanes, max_blocks, bs)
+    pool_formats = compiled.input_formats[0][1]
+    for name, fmt in pool_formats.items():
+        assert fmt.layout.major_to_minor[-1] != 1, (name, fmt.layout)
+    bodies = _loop_bodies(compiled.as_text())
+    assert bodies
+    pool_slice = re.compile(rf"\[[\d,]*\b{num_blocks},{bs}\b[\d,]*\]")
+    copies = [line.strip() for body in bodies for line in body.splitlines()
+              if re.search(r" copy(-start)?\(", line)
+              and pool_slice.search(line.split(" copy")[0])]
+    assert not copies, copies

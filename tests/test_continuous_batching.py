@@ -75,10 +75,14 @@ def _serve(cfg, params, prompts, max_new, *, batch_slots=4, max_seq=32,
 # Model level: paged decode == monolithic decode, bit for bit
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("arch", ["qwen2-0.5b", "mamba2-1.3b",
-                                  "zamba2-1.2b"])
-def test_paged_decode_matches_monolithic_decode(arch):
-    cfg = reduce_cfg(get_config(arch), dtype="float32")
+@pytest.mark.parametrize(
+    "arch,kv_dtype",
+    [("qwen2-0.5b", "bf16"), ("qwen2-0.5b", "int8"),
+     ("mamba2-1.3b", "bf16"), ("zamba2-1.2b", "bf16")],
+    ids=["qwen2-0.5b", "qwen2-0.5b-int8kv", "mamba2-1.3b", "zamba2-1.2b"])
+def test_paged_decode_matches_monolithic_decode(arch, kv_dtype):
+    cfg = reduce_cfg(get_config(arch), dtype="float32",
+                     kv_cache_dtype=kv_dtype)
     params = registry.init_params(cfg, jax.random.PRNGKey(0))
     B, S, bs = 3, 16, 4
     max_blocks = S // bs

@@ -347,11 +347,15 @@ def test_slot_engine_interleaved_prefill_regression(arch):
 # KV lane checkpoint/restore (the migration primitive)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("arch", ["qwen2-0.5b", "mamba2-1.3b",
-                                  "zamba2-1.2b"])
-def test_kv_lane_checkpoint_restore_roundtrip(arch):
+@pytest.mark.parametrize(
+    "arch,kv_dtype",
+    [("qwen2-0.5b", "bf16"), ("qwen2-0.5b", "int8"),
+     ("mamba2-1.3b", "bf16"), ("zamba2-1.2b", "bf16")],
+    ids=["qwen2-0.5b", "qwen2-0.5b-int8kv", "mamba2-1.3b", "zamba2-1.2b"])
+def test_kv_lane_checkpoint_restore_roundtrip(arch, kv_dtype):
     from repro.serve.kvcache import PagedKVCache
-    cfg = reduce_cfg(get_config(arch), dtype="float32")
+    cfg = reduce_cfg(get_config(arch), dtype="float32",
+                     kv_cache_dtype=kv_dtype)
     params = registry.init_params(cfg, jax.random.PRNGKey(0))
     pool = PagedKVCache(cfg, lanes=2, max_seq=32, block_size=4)
     lane = pool.assign("req", seq_len=1)
@@ -368,6 +372,13 @@ def test_kv_lane_checkpoint_restore_roundtrip(arch):
         lane.pos = t + 1
     ckpt = pool.checkpoint_lane(lane.index)
     assert ckpt["pos"] == 6
+    # every pooled leaf, [layers, num_blocks, block_size, KVH*width], is
+    # snapshotted as the lane's 2 blocks
+    pooled = [leaf for leaf in jax.tree_util.tree_leaves(pool.cache)
+              if leaf.shape[1:3] == (pool.num_blocks, 4)]
+    assert len(ckpt["blocks"]) == len(pooled)
+    for leaf, snap in zip(pooled, ckpt["blocks"].values()):
+        assert snap.shape == (leaf.shape[0], 2) + leaf.shape[2:]
     # restore into a FRESH pool (different block layout is fine: the
     # snapshot is logical positions, the table maps them to new blocks)
     pool2 = PagedKVCache(cfg, lanes=2, max_seq=32, block_size=4)
