@@ -75,12 +75,16 @@ class SchedulerStats:
     requests, so ``admitted - preemptions`` is the number of distinct
     residencies that ran to completion/failure.  ``prefill_calls`` is the
     number of fused chunked-prefill dispatches (each feeds every
-    mid-prefill lane one token) — the interleaving knob's observable.
-    ``decode_lanes`` sums the lanes fed over launched decode steps, so
-    over the engine's ``steps`` it is the mean batch of a decode step."""
+    mid-prefill lane up to ``prefill_chunk`` tokens) — the interleaving
+    knob's observable — and ``prefill_tokens`` the prompt positions they
+    fed, so ``prefill_tokens / prefill_calls`` is the positions one call
+    carries.  ``decode_lanes`` sums the lanes fed over launched decode
+    steps, so over the engine's ``steps`` it is the mean batch of a
+    decode step."""
     admitted: int = 0          # (re-)admissions into a lane
     preemptions: int = 0       # evictions under block pressure
     prefill_calls: int = 0     # fused chunked-prefill dispatches
+    prefill_tokens: int = 0    # prompt positions fed by prefill
     decode_lanes: int = 0      # lanes fed, summed over decode steps
     peak_resident: int = 0     # max lanes occupied at once
     peak_backlog: int = 0      # max requests waiting for lanes/blocks
@@ -88,7 +92,8 @@ class SchedulerStats:
     def format(self) -> str:
         return (f"scheduler: {self.admitted} admitted "
                 f"({self.preemptions} preemptions), "
-                f"{self.prefill_calls} prefill chunks, "
+                f"{self.prefill_calls} prefill chunks "
+                f"({self.prefill_tokens} tokens), "
                 f"{self.decode_lanes} decode lanes; peaks: "
                 f"{self.peak_resident} resident, "
                 f"{self.peak_backlog} backlogged")

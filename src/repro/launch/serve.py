@@ -55,8 +55,9 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "block (0 = slots*ceil(max_seq/block)+1, i.e. "
                          "the fixed-slot capacity)")
     ap.add_argument("--prefill-chunk", type=int, default=8,
-                    help="fused prefill calls interleaved per admission "
-                         "round before decode resumes (paged mode)")
+                    help="prompt positions each mid-prefill lane is fed "
+                         "by one fused prefill call, run between decode "
+                         "steps")
     ap.add_argument("--devices", type=int, default=0,
                     help="CPU rehearsal on N forced host devices (selects "
                          "the CPU platform)")

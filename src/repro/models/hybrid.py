@@ -299,15 +299,16 @@ def reset_paged_lane(cfg: ModelConfig, cache, lane_index: int):
     return new
 
 
-def _shared_attn_paged(cfg, sp, lora, x, kc, vc, pos, tables):
+def _shared_attn_paged(cfg, sp, lora, x, kc, vc, pos, tables, live):
     """Shared attention + MLP block against the paged KV pool of one
-    site.  kc/vc: [num_blocks, bs, KVH*hd]; tables: [B, max_blocks]."""
+    site, one position per lane.  kc/vc: [num_blocks, bs, KVH*hd];
+    tables: [B, max_blocks]; live: [B, 1] bool or None."""
     from repro.models.transformer import _paged_view, paged_scatter
     ap = dict(sp["attn"])
     ap.update(lora)
     h = L.rmsnorm(x, sp["ln1"], cfg.rms_norm_eps)
     q, k, v = L.attn_qkv(ap, h, pos[:, None], cfg)
-    kc, vc = paged_scatter(kc, vc, k[:, 0], v[:, 0], tables, pos)
+    kc, vc = paged_scatter(kc, vc, k, v, tables, pos, live)
     o = L.decode_attention_merged(q, _paged_view(kc, tables),
                                   _paged_view(vc, tables), pos)
     x = x + L.attn_out(ap, o)
@@ -326,7 +327,16 @@ def decode_step_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
 
 def decode_hidden_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
                         fed=None):
+    """The transformer's paged contract (tokens [B,T], pos [B], ``fed``
+    [B] leading columns fed); T > 1 runs this one-token step over the
+    columns (``mamba.scan_columns``), shared attention included."""
     from repro.models.transformer import embed_tokens
+    if tokens.shape[1] > 1:
+        return M.scan_columns(
+            lambda c, tok, p, f: decode_hidden_paged(params, cfg, c, tok, p,
+                                                     tables, f),
+            cache, tokens, pos, fed)
+    live = L.fed_columns(fed, 1)
     x = embed_tokens(params, cfg, tokens)
     n_groups, k, tail = group_layout(cfg)
 
@@ -344,13 +354,13 @@ def decode_hidden_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
             st = _stack_index(sts, i)
             h = L.rmsnorm(x, gn[i], cfg.rms_norm_eps)
             y, new_st = M.block_decode(bp, cfg, st, h)
-            if fed is not None:
-                new_st = M.masked_state(fed, new_st, st)
+            if live is not None:
+                new_st = M.masked_state(live[:, 0], new_st, st)
             x = x + y
             new_sts.append(new_st)
         sts = jax.tree.map(lambda *a: jnp.stack(a), *new_sts)
         x, kc, vc = _shared_attn_paged(cfg, params["shared"], lora, x,
-                                       kc, vc, pos, tables)
+                                       kc, vc, pos, tables, live)
         return x, (sts, kc, vc)
 
     x, (new_ssm, new_k, new_v) = jax.lax.scan(
@@ -368,8 +378,8 @@ def decode_hidden_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
             st = _stack_index(cache["tail_ssm"], i)
             h = L.rmsnorm(x, params["tail_norms"][i], cfg.rms_norm_eps)
             y, new_st = M.block_decode(bp, cfg, st, h)
-            if fed is not None:
-                new_st = M.masked_state(fed, new_st, st)
+            if live is not None:
+                new_st = M.masked_state(live[:, 0], new_st, st)
             x = x + y
             tail_sts.append(new_st)
         new_cache["tail_ssm"] = jax.tree.map(lambda *a: jnp.stack(a), *tail_sts)

@@ -348,37 +348,68 @@ def decode_attention(q, k_cache, v_cache, pos, *, logit_cap: float = 0.0):
 
 def decode_attention_merged(q, k_view, v_view, pos, *,
                             logit_cap: float = 0.0):
-    """``decode_attention`` against views whose KV heads and head_dim
-    share one minor dimension, as the paged pool stores them.
+    """``decode_attention`` for T query positions per lane against views
+    whose KV heads and head_dim share one minor dimension, as the paged
+    pool stores them.
 
-    q: [B, 1, H, hd]; views: [B, S, KVH*hd]; pos: [B] (#valid entries).
-    Each KV head's query group contracts with that head's columns of the
-    view, so the view is read in its stored layout and never split into
-    (KVH, hd); each head's products and sums are those of
-    ``decode_attention``, so the two agree bit for bit.
+    q: [B, T, H, hd], lane b's query t at position pos[b] + t; views:
+    [B, S, KVH*hd]; pos: [B].  Query t of lane b sees keys
+    s <= pos[b] + t.  Each KV head's T*G query rows contract with that
+    head's columns of the view, so the view is read in its stored layout
+    and never split into (KVH, hd); at T = 1 each head's products and
+    sums are those of ``decode_attention``, so the two agree bit for bit.
     """
-    B, _, H, hd = q.shape
+    B, T, H, hd = q.shape
     _, S, W = k_view.shape
     KVH = W // hd
     G = H // KVH
     scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, KVH, G, hd).astype(jnp.float32) * scale
+    # rows of one KV head ordered (t, g): row r is position pos + r // G
+    qg = (q.reshape(B, T, KVH, G, hd).transpose(0, 2, 1, 3, 4)
+          .reshape(B, KVH, T * G, hd).astype(jnp.float32) * scale)
     cols = [slice(h * hd, (h + 1) * hd) for h in range(KVH)]
     s = jnp.stack([jnp.einsum("bgd,bsd->bgs", qg[:, h],
                               k_view[:, :, c].astype(jnp.float32),
                               preferred_element_type=jnp.float32)
-                   for h, c in enumerate(cols)], axis=1)     # [B,KVH,G,S]
+                   for h, c in enumerate(cols)], axis=1)   # [B,KVH,T*G,S]
     s = softcap(s, logit_cap) if logit_cap else s
-    valid = jnp.arange(S)[None, :] < pos[:, None] + 1       # [B,S]
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    if T == 1:
+        valid = (jnp.arange(S)[None, :] < pos[:, None] + 1)[:, None, :]
+    else:
+        last = pos[:, None] + jnp.arange(T * G)[None, :] // G   # [B,T*G]
+        valid = jnp.arange(S)[None, None, :] <= last[:, :, None]
+    s = jnp.where(valid[:, None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
     p = (p / l).astype(v_view.dtype)
     out = jnp.stack([jnp.einsum("bgs,bsd->bgd", p[:, h], v_view[:, :, c],
                                 preferred_element_type=jnp.float32)
-                     for h, c in enumerate(cols)], axis=1)   # [B,KVH,G,hd]
-    return out.reshape(B, 1, H, hd).astype(q.dtype)
+                     for h, c in enumerate(cols)], axis=1)  # [B,KVH,T*G,hd]
+    out = out.reshape(B, KVH, T, G, hd).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, T, H, hd).astype(q.dtype)
+
+
+def fed_columns(fed, T: int):
+    """[B, T] bool: which of a call's T columns each lane is fed, from
+    ``fed`` [B], the number of leading columns fed (a bool is a count of
+    0 or 1); ``None`` (every column fed) stays ``None``."""
+    if fed is None:
+        return None
+    if T == 1 and fed.dtype == jnp.bool_:
+        return fed[:, None]
+    return jnp.arange(T)[None, :] < fed.astype(jnp.int32)[:, None]
+
+
+def last_fed(x, fed):
+    """Each lane's last fed column of x [B, T, ...] as [B, 1, ...]
+    (column 0 for a lane fed nothing), for ``fed`` as ``fed_columns``
+    takes it; x itself at T = 1."""
+    B, T = x.shape[:2]
+    if T == 1:
+        return x
+    n = jnp.full((B,), T) if fed is None else fed.astype(jnp.int32)
+    return x[jnp.arange(B), jnp.clip(n - 1, 0, T - 1)][:, None]
 
 
 # ---------------------------------------------------------------------------

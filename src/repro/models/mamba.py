@@ -400,18 +400,49 @@ def decode_step_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
 
 def decode_hidden_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
                         fed=None):
+    """tokens [B,T], pos [B], ``fed`` [B] leading columns fed (None =
+    all): the transformer's paged contract.  T > 1 runs this one-token
+    step over the columns (``scan_columns``)."""
     from repro.models.transformer import embed_tokens
+    if tokens.shape[1] > 1:
+        return scan_columns(
+            lambda c, tok, p, f: decode_hidden_paged(params, cfg, c, tok, p,
+                                                     tables, f),
+            cache, tokens, pos, fed)
+    live = L.fed_columns(fed, 1)
     x = embed_tokens(params, cfg, tokens)
 
     def body(x, scanned):
         bp, nrm, st = scanned
         h = L.rmsnorm(x, nrm, cfg.rms_norm_eps)
         y, new_st = block_decode(bp, cfg, st, h)
-        if fed is not None:
-            new_st = masked_state(fed, new_st, st)
+        if live is not None:
+            new_st = masked_state(live[:, 0], new_st, st)
         return x + y, new_st
 
     x, new_state = jax.lax.scan(
         body, x, (params["blocks"], params["block_norms"], cache))
     x = L.rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     return x, new_state
+
+
+def scan_columns(step, cache, tokens, pos, fed):
+    """T columns of ``tokens`` [B,T] through a one-token paged step, in
+    one program, for families with recurrent state.  ``step(cache,
+    tokens [B,1], pos [B], fed [B] bool) -> (hidden [B,1,D], cache)``
+    runs once per column t, at positions pos + t, fed to the lanes given
+    more than t columns: the arithmetic of T one-token calls.  Returns
+    the hidden state [B,1,D] of each lane's last fed column, and the
+    cache."""
+    B, T = tokens.shape
+    cols = L.fed_columns(fed, T)
+    if cols is None:
+        cols = jnp.ones((B, T), bool)
+
+    def body(cache, column):
+        tok, live, t = column
+        x, cache = step(cache, tok[:, None], pos + t, live)
+        return cache, x[:, 0]
+
+    cache, xs = jax.lax.scan(body, cache, (tokens.T, cols.T, jnp.arange(T)))
+    return L.last_fed(xs.transpose(1, 0, 2), fed), cache

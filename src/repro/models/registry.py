@@ -130,6 +130,11 @@ def init_paged_cache(cfg, lanes, num_blocks, block_size):
 
 
 def decode_step_paged(params, cfg, cache, tokens, pos, tables, fed=None):
+    """tokens [B,T] at positions pos[b] + t, tables [B,max_blocks],
+    ``fed`` [B] the leading columns each lane is really fed (a bool is a
+    count of 0 or 1; None = all) -> (logits [B,1,V] of each lane's last
+    fed column, updated cache).  T = 1 is a decode step; the serve
+    engine's prefill chunk is T = prefill_chunk."""
     return module_for(cfg).decode_step_paged(params, cfg, cache, tokens,
                                              pos, tables, fed)
 

@@ -220,7 +220,8 @@ def cache_shapes(cfg: ModelConfig, batch: int, max_seq: int):
 
 
 def _quantize_kv(t):
-    """t: [B,KVH,hd] -> (int8 [B,KVH,hd], f32 scale [B,KVH,1])."""
+    """t: [..., KVH, hd] -> (int8 [..., KVH, hd], f32 scale
+    [..., KVH, 1]): one scale per position and KV head."""
     tf = t.astype(jnp.float32)
     scale = jnp.maximum(jnp.max(jnp.abs(tf), axis=-1, keepdims=True) / 127.0,
                         1e-12)
@@ -308,13 +309,15 @@ def decode_hidden(params, cfg: ModelConfig, cache, tokens, pos, fed=None):
 # The paged layout replaces the per-slot [B, max_seq] cache rows with a
 # shared pool of fixed-size blocks [num_blocks, block_size]; each decode
 # lane carries a block *table* [max_blocks] of physical pool indices.
-# Per step, the new token's K/V is scattered into (table[pos//bs],
-# pos%bs) and attention runs over the table-gathered view
+# Per call, each lane's T new positions have their K/V scattered into
+# (table[p//bs], p%bs) and attention runs over the table-gathered view
 # [B, max_blocks*block_size, KVH*hd] — the same masked attention as the
-# monolithic path, with positions > pos masked to exact zeros, so stale
-# bytes in recycled blocks (and the shared scratch block 0 behind
-# unallocated table entries) are unreachable and the logits are
-# bit-identical to a monolithic cache row's.
+# monolithic path, with positions past each query's own masked to exact
+# zeros, so stale bytes in recycled blocks (and the shared scratch block
+# 0 behind unallocated table entries) are unreachable.  A decode step is
+# T = 1, and its logits are bit-identical to a monolithic cache row's; a
+# prefill chunk is T = prefill_chunk positions of every mid-prefill lane
+# in one call.
 #
 # Every block-pooled leaf is [layers, num_blocks, block_size, KVH*width]:
 # heads and head_dim share one minor dimension (int8 scale pools have
@@ -360,18 +363,24 @@ def reset_paged_lane(cfg: ModelConfig, cache, lane_index: int):
     return cache
 
 
-def paged_scatter(kc, vc, k_new, v_new, tables, pos):
-    """Scatter one token's K/V [B, KVH, width] into the pools
-    [num_blocks, bs, KVH*width] at (table[pos//bs], pos%bs).  Lanes
-    whose table entry is the scratch block (idle lanes) land at physical
-    block 0 — never gathered by a live table, so the duplicate writes
-    are harmless."""
-    B = k_new.shape[0]
+def paged_scatter(kc, vc, k_new, v_new, tables, pos, live=None):
+    """Scatter T positions' K/V [B, T, KVH, width] into the pools
+    [num_blocks, bs, KVH*width]: lane b's column t at position
+    pos[b] + t, i.e. (table[(pos+t)//bs], (pos+t)%bs).  ``live`` [B, T]
+    bool (``layers.fed_columns``) sends the columns a lane is not fed to
+    the scratch block 0, as idle lanes' whole tables already point there:
+    an unfed column past the lane's capacity would otherwise land, by the
+    gather's index clamp, in its last live block.  Block 0 is never
+    gathered unmasked, so the duplicate writes there are harmless."""
+    B, T = k_new.shape[:2]
     bs = kc.shape[1]
-    phys = tables[jnp.arange(B), pos // bs]
-    off = pos % bs
-    return (kc.at[phys, off].set(k_new.reshape(B, -1)),
-            vc.at[phys, off].set(v_new.reshape(B, -1)))
+    at = pos[:, None] + jnp.arange(T)[None, :]                  # [B,T]
+    phys = tables[jnp.arange(B)[:, None], at // bs]
+    if live is not None:
+        phys = jnp.where(live, phys, 0)
+    phys, off = phys.reshape(-1), (at % bs).reshape(-1)
+    return (kc.at[phys, off].set(k_new.reshape(B * T, -1)),
+            vc.at[phys, off].set(v_new.reshape(B * T, -1)))
 
 
 def _paged_view(pool, tables):
@@ -392,28 +401,31 @@ def _dequant_view(cfg: ModelConfig, view, scale):
     return full.reshape(B, S, W).astype(cfg.dtype)
 
 
-def _layer_decode_paged(cfg: ModelConfig, x, lp, kc, vc, pos, tables,
+def _layer_decode_paged(cfg: ModelConfig, x, lp, kc, vc, pos, tables, live,
                         ks=None, vs=None):
-    """One decoded token through one layer against the paged pool.
-    x: [B,1,D]; kc/vc: [num_blocks, bs, KVH*hd] (int8 with ks/vs scale
+    """T positions per lane through one layer against the paged pool.
+    x: [B,T,D]; kc/vc: [num_blocks, bs, KVH*hd] (int8 with ks/vs scale
     pools [num_blocks, bs, KVH] when cfg.kv_cache_dtype == "int8");
-    tables: [B, max_blocks]."""
+    tables: [B, max_blocks]; live: [B,T] bool or None.  The chunk's own
+    keys are scattered before the view is gathered, so each position
+    attends to the chunk's earlier positions and the cached prefix."""
+    T = x.shape[1]
     with jax.named_scope("attention"):
         h = L.rmsnorm(x, lp["ln1"], cfg.rms_norm_eps)
-        q, k_new, v_new = L.attn_qkv(lp["attn"], h, pos[:, None], cfg)
+        q, k_new, v_new = L.attn_qkv(lp["attn"], h,
+                                     pos[:, None] + jnp.arange(T), cfg)
     with jax.named_scope("paged_view"):
         if ks is not None:
-            kq, ksc = _quantize_kv(k_new[:, 0])
-            vq, vsc = _quantize_kv(v_new[:, 0])
-            kc, vc = paged_scatter(kc, vc, kq, vq, tables, pos)
-            ks, vs = paged_scatter(ks, vs, ksc, vsc, tables, pos)
+            kq, ksc = _quantize_kv(k_new)
+            vq, vsc = _quantize_kv(v_new)
+            kc, vc = paged_scatter(kc, vc, kq, vq, tables, pos, live)
+            ks, vs = paged_scatter(ks, vs, ksc, vsc, tables, pos, live)
             k_use = _dequant_view(cfg, _paged_view(kc, tables),
                                   _paged_view(ks, tables))
             v_use = _dequant_view(cfg, _paged_view(vc, tables),
                                   _paged_view(vs, tables))
         else:
-            kc, vc = paged_scatter(kc, vc, k_new[:, 0], v_new[:, 0], tables,
-                                   pos)
+            kc, vc = paged_scatter(kc, vc, k_new, v_new, tables, pos, live)
             k_use = _paged_view(kc, tables)
             v_use = _paged_view(vc, tables)
     with jax.named_scope("attention"):
@@ -431,11 +443,9 @@ def _layer_decode_paged(cfg: ModelConfig, x, lp, kc, vc, pos, tables,
 
 def decode_step_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
                       fed=None):
-    """tokens [B,1], pos [B], tables [B,max_blocks] -> (logits [B,1,V],
-    updated pool cache).  ``fed`` ([B] bool, which lanes carry a real
-    token this call) is unused here: attention KV at a non-fed lane's
-    next-write position is overwritten by its next real token before the
-    mask ever exposes it."""
+    """tokens [B,T], pos [B], tables [B,max_blocks] -> (logits [B,1,V] of
+    each lane's last fed column, updated pool cache).  See
+    ``decode_hidden_paged`` for ``pos`` and ``fed``."""
     x, new_cache = decode_hidden_paged(params, cfg, cache, tokens, pos,
                                        tables, fed)
     with jax.named_scope("logits"):
@@ -444,8 +454,16 @@ def decode_step_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
 
 def decode_hidden_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
                         fed=None):
-    """Paged decode step up to (and including) the final norm."""
+    """Paged step up to (and including) the final norm: lane b's T
+    columns of ``tokens`` [B,T] are positions pos[b] .. pos[b]+T-1, and
+    ``fed`` [B] (None = all) is how many leading columns the lane is
+    really fed; T = 1 is one decode step.  Unfed columns write the
+    scratch block and their outputs are dropped (at T = 1 an unfed
+    lane's next-write position would be overwritten before the mask
+    exposes it anyway).  Returns the hidden state [B,1,D] of each lane's
+    last fed column, and the updated pool."""
     x = embed_tokens(params, cfg, tokens)
+    live = L.fed_columns(fed, tokens.shape[1])
     int8 = cfg.kv_cache_dtype == "int8"
 
     def body(x, scanned):
@@ -455,7 +473,7 @@ def decode_hidden_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
             lp, kc, vc = scanned
             ks = vs = None
         x, kc, vc, ks, vs = _layer_decode_paged(cfg, x, lp, kc, vc, pos,
-                                                tables, ks, vs)
+                                                tables, live, ks, vs)
         return x, ((kc, vc, ks, vs) if int8 else (kc, vc))
 
     if int8:
@@ -468,7 +486,7 @@ def decode_hidden_paged(params, cfg: ModelConfig, cache, tokens, pos, tables,
         x, (k_new, v_new) = jax.lax.scan(
             body, x, (params["layers"], cache["k"], cache["v"]))
         new_cache = {"k": k_new, "v": v_new}
-    x = L.rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = L.rmsnorm(L.last_fed(x, fed), params["final_norm"], cfg.rms_norm_eps)
     return x, new_cache
 
 

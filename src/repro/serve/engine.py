@@ -123,8 +123,9 @@ class GenRequest:
 
 def _serve_program(cfg):
     """The unsharded serving program: one fused paged call that prefill
-    and decode both dispatch.  Jitted from a named function, so that the
-    device trace names its module ``jit_serve_call``.  ``cache`` is the
+    (tokens ``[B, prefill_chunk]``) and decode (``[B, 1]``) both
+    dispatch.  Jitted from a named function, so that the device trace
+    names both shapes' modules ``jit_serve_call``.  ``cache`` is the
     block pool, leaves ``[layers, num_blocks, block_size, KVH*hd]``
     (``serve/kvcache.py``); it is not donated, so the pre-call pool
     stays valid for a failed step or prefill chunk."""
@@ -317,7 +318,7 @@ class ServeEngine:
         # stages may run on different executor workers, but KV cache and
         # slot state are shared.  Prefill itself runs OUTSIDE the lock
         # (staged cache, published atomically) so submit() and the
-        # detokenize path never block behind a token-by-token prompt loop.
+        # detokenize path never block behind a prefill chunk.
         self._lock = debug.make_lock("ServeEngine._lock")
         self._decode_inflight = None
         self._current_step = None      # the step whose continuation owns state
@@ -513,8 +514,8 @@ class ServeEngine:
         """Continuous-batching admission: drain arrivals into the
         length-bucketed backlog, admit whatever fits the free lanes AND
         free blocks (lane + prefill blocks claimed atomically), then run
-        ONE chunk of batched prefill — at most ``prefill_chunk`` fused
-        calls, each feeding EVERY mid-prefill lane its next replay token.
+        ONE chunk of batched prefill — one fused call feeding EVERY
+        mid-prefill lane up to ``prefill_chunk`` next replay tokens.
         Long prompts therefore interleave with decode steps instead of
         blocking them: the caller (admit task / detokenize continuation)
         re-schedules until every replay is rebuilt.
@@ -600,35 +601,42 @@ class ServeEngine:
         return admitted
 
     def _prefill_chunk(self, cache):
-        """Up to ``prefill_chunk`` fused paged calls over the staged
-        cache; logits are discarded (and in sharded mode no gather is
-        started) — prefill only needs the KV side effect.  Lanes not
-        being fed freeze their SSM state via the ``fed`` mask; their
-        attention scratch writes are overwritten before the causal mask
-        can expose them (see models/transformer.py).  Returns the staged
-        cache and the lanes whose replay completed."""
-        for _ in range(self.prefill_chunk):
-            feeding = [(idx, req) for idx, req in self._prefilling.items()
-                       if req.prefill_pos < len(req.replay) - 1]
-            if not feeding:
-                break
+        """One fused paged call of ``[batch_slots, prefill_chunk]``
+        tokens over the staged cache: each mid-prefill lane is fed its
+        next ``min(prefill_chunk, remaining)`` replay positions, the
+        other lanes none.  Every chunk pads to the same shape, so prefill
+        and decode (``[batch_slots, 1]``) are the serving program's only
+        two shapes.  Logits are discarded (and in sharded mode no gather
+        is started) — prefill only needs the KV side effect.  Columns a
+        lane is not fed write the scratch block and leave its SSM state
+        frozen (see models/transformer.py).  Returns the staged cache and
+        the lanes whose replay completed."""
+        feeding = [(idx, req) for idx, req in self._prefilling.items()
+                   if req.prefill_pos < len(req.replay) - 1]
+        if feeding:
+            C = self.prefill_chunk
+            toks = np.zeros((self.batch_slots, C), np.int32)
+            fed = np.zeros((self.batch_slots,), np.int32)
+            for idx, req in feeding:
+                n = min(C, len(req.replay) - 1 - req.prefill_pos)
+                toks[idx, :n] = req.replay[req.prefill_pos:
+                                           req.prefill_pos + n]
+                fed[idx] = n
+            tokens = int(fed.sum())
             self._calls += 1
             with jax.profiler.TraceAnnotation("serve.prefill",
                                               call=self._calls,
-                                              lanes=len(feeding)):
-                toks = np.zeros((self.batch_slots, 1), np.int32)
-                fed = np.zeros((self.batch_slots,), bool)
-                for idx, req in feeding:
-                    toks[idx, 0] = int(req.replay[req.prefill_pos])
-                    fed[idx] = True
+                                              lanes=len(feeding),
+                                              tokens=tokens):
                 _, cache = self._jit_decode(
                     self.params, cache, jnp.asarray(toks),
                     self.slots.positions(), self.slots.block_tables(),
                     jnp.asarray(fed))
             for idx, req in feeding:
-                req.prefill_pos += 1
-                self.slots.slots[idx].pos += 1
+                req.prefill_pos += int(fed[idx])
+                self.slots.slots[idx].pos += int(fed[idx])
             self.sched.prefill_calls += 1
+            self.sched.prefill_tokens += tokens
         completed = []
         for idx, req in self._prefilling.items():
             if req.prefill_pos >= len(req.replay) - 1:

@@ -56,10 +56,13 @@ def _sds(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _compile_paged_step(cfg, sharding, lanes, max_blocks, bs):
+def _compile_paged_step(cfg, sharding, lanes, max_blocks, bs, chunk=1):
     """The serve engine's unsharded serving program, compiled for
     ``lanes`` lanes of ``max_blocks`` blocks of ``bs`` positions over a
-    pool of ``lanes * max_blocks + 1`` blocks."""
+    pool of ``lanes * max_blocks + 1`` blocks: the decode step's
+    ``[lanes, 1]`` tokens and fed mask, or with ``chunk`` > 1 a prefill
+    chunk's ``[lanes, chunk]`` tokens and fed counts."""
+    fed = _sds((lanes,), jnp.bool_ if chunk == 1 else jnp.int32)
     params = jax.eval_shape(
         lambda: registry.init_params(cfg, jax.random.PRNGKey(0)))
     cache = jax.eval_shape(lambda: registry.init_paged_cache(
@@ -68,8 +71,9 @@ def _compile_paged_step(cfg, sharding, lanes, max_blocks, bs):
         lambda p, c, t, q, bt, fd: registry.decode_step_paged(
             p, cfg, c, t, q, bt, fd)
     ).lower(*_on(sharding, (
-        params, cache, _sds((lanes, 1), jnp.int32), _sds((lanes,), jnp.int32),
-        _sds((lanes, max_blocks), jnp.int32), _sds((lanes,), jnp.bool_)))
+        params, cache, _sds((lanes, chunk), jnp.int32),
+        _sds((lanes,), jnp.int32), _sds((lanes, max_blocks), jnp.int32),
+        fed))
     ).compile()
 
 
@@ -142,15 +146,9 @@ def test_qwen2_paged_decode_step_compiles(one_chip):
     assert 0 < used < 16e9
 
 
-def test_qwen2_paged_pool_is_not_relaid_out_per_layer(one_chip):
-    """At the serving benchmark's shapes (64 lanes of 161 blocks of 16,
-    a pool of 10,305 blocks) the pool enters with its blocks dimension
-    major, and the layer loop copies no per-layer pool slice.  A pool
-    stored as [.., KVH, hd] = [.., 2, 64] took a blocks-minor layout, and
-    each layer relaid out its K and V slices four times."""
-    lanes, max_blocks, bs = 64, 161, 16
-    num_blocks = lanes * max_blocks + 1
-    compiled = _compile_paged_step(QWEN, one_chip, lanes, max_blocks, bs)
+def _assert_pool_not_relaid_out(compiled, num_blocks, bs):
+    """The pool enters with its blocks dimension major, and no loop body
+    copies a pool slice."""
     pool_formats = compiled.input_formats[0][1]
     for name, fmt in pool_formats.items():
         assert fmt.layout.major_to_minor[-1] != 1, (name, fmt.layout)
@@ -161,3 +159,25 @@ def test_qwen2_paged_pool_is_not_relaid_out_per_layer(one_chip):
               if re.search(r" copy(-start)?\(", line)
               and pool_slice.search(line.split(" copy")[0])]
     assert not copies, copies
+
+
+def test_qwen2_paged_pool_is_not_relaid_out_per_layer(one_chip):
+    """At the serving benchmark's shapes (64 lanes of 161 blocks of 16,
+    a pool of 10,305 blocks) the pool enters with its blocks dimension
+    major, and the layer loop copies no per-layer pool slice.  A pool
+    stored as [.., KVH, hd] = [.., 2, 64] took a blocks-minor layout, and
+    each layer relaid out its K and V slices four times."""
+    lanes, max_blocks, bs = 64, 161, 16
+    compiled = _compile_paged_step(QWEN, one_chip, lanes, max_blocks, bs)
+    _assert_pool_not_relaid_out(compiled, lanes * max_blocks + 1, bs)
+
+
+def test_qwen2_paged_prefill_chunk_keeps_the_pool_layout(one_chip):
+    """The benchmark's prefill chunk, ``[64, 8]`` tokens through the same
+    serving program, scatters eight positions per lane and attends over
+    them: the pool keeps the decode step's blocks-major layout, and the
+    layer loop still copies no pool slice."""
+    lanes, max_blocks, bs = 64, 161, 16
+    compiled = _compile_paged_step(QWEN, one_chip, lanes, max_blocks, bs,
+                                   chunk=8)
+    _assert_pool_not_relaid_out(compiled, lanes * max_blocks + 1, bs)

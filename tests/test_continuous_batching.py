@@ -127,6 +127,101 @@ def test_fed_mask_freezes_ssm_state():
         assert float(jnp.max(jnp.abs(new[:, 0] - old[:, 0]))) > 0.0
 
 
+def _random_tree(tree, key):
+    """Every leaf of ``tree`` filled with random values of its dtype."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    out = []
+    for leaf, k in zip(leaves, jax.random.split(key, len(leaves))):
+        if leaf.dtype == jnp.int8:
+            out.append(jax.random.randint(k, leaf.shape, -127, 128,
+                                          jnp.int32).astype(jnp.int8))
+        else:
+            out.append(jax.random.normal(k, leaf.shape, leaf.dtype))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@pytest.mark.parametrize(
+    "arch,kv_dtype",
+    [("qwen2-0.5b", "bf16"), ("qwen2-0.5b", "int8"),
+     ("mamba2-1.3b", "bf16"), ("zamba2-1.2b", "bf16")],
+    ids=["qwen2-0.5b", "qwen2-0.5b-int8kv", "mamba2-1.3b", "zamba2-1.2b"])
+def test_paged_chunk_matches_one_token_calls(arch, kv_dtype):
+    """One [B, C] paged call equals C one-token calls: the logits of each
+    lane's last fed column and the pool at the fed positions agree (to
+    float32 rounding where attention batches the chunk's rows, exactly
+    where a family scans its columns), and nothing a lane is not fed is
+    written: a lane fed nothing keeps its blocks and state bit for bit,
+    and a lane near capacity does not clamp its unfed columns into its
+    last live block."""
+    cfg = reduce_cfg(get_config(arch), dtype="float32",
+                     kv_cache_dtype=kv_dtype)
+    params = registry.init_params(cfg, jax.random.PRNGKey(0))
+    B, C, bs, max_blocks = 5, 4, 4, 4               # lane capacity 16
+    num_blocks = 1 + B * max_blocks
+    tables = jnp.asarray(1 + np.arange(B * max_blocks).reshape(
+        B, max_blocks), jnp.int32)
+    cache = _random_tree(registry.init_paged_cache(cfg, B, num_blocks, bs),
+                         jax.random.PRNGKey(1))
+    pos = np.array([3, 5, 2, 6, 14], np.int32)      # lane 4: 2 of 4 fit
+    counts = np.array([0, 1, 3, C, 2], np.int32)
+    toks = jax.random.randint(jax.random.PRNGKey(2), (B, C), 0,
+                              cfg.vocab_size)
+    # both sides jitted, as the engine serves them
+    step = jax.jit(lambda c, t, p, f: registry.decode_step_paged(
+        params, cfg, c, t, p, tables, f))
+    lg, chunk = step(cache, toks, jnp.asarray(pos), jnp.asarray(counts))
+    assert lg.shape == (B, 1, cfg.vocab_size)
+    ref, ref_lg = cache, np.zeros(lg.shape, np.float32)
+    for t in range(C):
+        out, ref = step(ref, toks[:, t:t + 1], jnp.asarray(pos + t),
+                        jnp.asarray(counts > t))
+        last = counts - 1 == t
+        ref_lg[last] = np.asarray(out)[last]
+    exact = not registry.paged_has_blocks(cfg) or cfg.family == "hybrid"
+    fed_lanes = counts > 0
+    gap = np.abs(np.asarray(lg)[fed_lanes] - ref_lg[fed_lanes]).max()
+    if exact:
+        assert gap == 0.0, gap
+    else:
+        assert gap <= 1e-4 * np.abs(ref_lg).max(), gap
+    # the (block, offset) of every fed position
+    written = np.zeros((num_blocks, bs), bool)
+    for b in range(B):
+        for p in range(pos[b], pos[b] + counts[b]):
+            written[int(tables[b, p // bs]), p % bs] = True
+    written[0] = True                               # scratch block
+    for path, c0 in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        key = jax.tree_util.keystr(path)
+        c0 = np.asarray(c0)
+        got = np.asarray(_leaf(chunk, path))
+        want = np.asarray(_leaf(ref, path))
+        if c0.ndim >= 3 and c0.shape[1:3] == (num_blocks, bs):
+            # pooled: untouched where nothing was fed, bit for bit
+            assert (got[:, ~written] == c0[:, ~written]).all(), key
+            live = written.copy()
+            live[0] = False
+            if exact:
+                assert (got[:, live] == want[:, live]).all(), key
+            elif c0.dtype == np.int8:               # a rounding step apart
+                diff = got[:, live].astype(int) - want[:, live]
+                assert np.abs(diff).max() <= 1, key
+            else:
+                np.testing.assert_allclose(got[:, live], want[:, live],
+                                           rtol=1e-4, atol=1e-4 *
+                                           np.abs(want[:, live]).max(),
+                                           err_msg=key)
+        else:
+            # lane-indexed recurrent state: the unfed lane is frozen
+            assert (got[:, 0] == c0[:, 0]).all(), key
+            assert (got == want).all(), key
+
+
+def _leaf(tree, path):
+    for k in path:
+        tree = tree[k.key]
+    return tree
+
+
 # ---------------------------------------------------------------------------
 # Engine level: token streams are invariant to the pool shape
 # ---------------------------------------------------------------------------
@@ -185,6 +280,27 @@ class TestPagedEngineEquivalence:
         # 8 requests through 2 lanes: later arrivals waited measurably
         assert lat.queued_ms_mean is not None
         assert lat.queued_ms_p99 >= lat.queued_ms_p50 >= 0.0
+
+
+@pytest.mark.parametrize("lengths,chunk", [
+    ([9], 4), ([2, 5, 12], 4), ([17], 8), ([1, 1], 8), ([6, 3], 1)])
+def test_prompt_prefills_in_one_call_per_chunk(tiny, lengths, chunk):
+    """Prompts admitted together are prefilled in ceil((L-1)/C) calls
+    for the longest L, each call feeding every mid-prefill lane up to C
+    positions; ``prefill_tokens`` counts every prompt position but the
+    last (the first decode step's input), and the streams are those of
+    prefill one position per call."""
+    cfg, params = tiny
+    rng = np.random.RandomState(len(lengths) * 100 + chunk)
+    prompts = [rng.randint(1, cfg.vocab_size - 1, size=n).astype(np.int32)
+               for n in lengths]
+    got, lat, sched, _ = _serve(cfg, params, prompts, 3,
+                                prefill_chunk=chunk)
+    assert lat.completed == len(prompts) and sched.preemptions == 0
+    assert sched.prefill_calls == -(-(max(lengths) - 1) // chunk)
+    assert sched.prefill_tokens == sum(n - 1 for n in lengths)
+    ref, _, _, _ = _serve(cfg, params, prompts, 3, prefill_chunk=1)
+    assert got == ref
 
 
 class TestBacklogAndBlocks:
@@ -275,9 +391,10 @@ class TestPagedChaos:
         return srv, eng
 
     def test_prefill_chunk_failure_frees_blocks(self, tiny):
-        """Kill the fused call mid-chunk: every mid-prefill request is
-        failed exactly once, all blocks and lanes return to the free
-        lists, and the engine still serves afterwards."""
+        """Kill the fused call of the second prefill chunk: every
+        mid-prefill request is failed exactly once, all blocks and lanes
+        return to the free lists, and the engine still serves
+        afterwards."""
         srv, eng = self._engine(tiny)
         usable = srv.slots.allocator.usable_blocks
         real = srv._jit_decode
@@ -285,12 +402,13 @@ class TestPagedChaos:
 
         def boom(*a):
             calls["n"] += 1
-            if calls["n"] >= 2:                  # mid-chunk, not at entry
+            if calls["n"] >= 2:                  # second chunk, not first
                 raise RuntimeError("prefill chunk boom")
             return real(*a)
 
         srv._jit_decode = boom
-        reqs = [GenRequest(f"c{i}", np.arange(1, 8, dtype=np.int32),
+        # 10 prefill positions each: two chunks of prefill_chunk = 8
+        reqs = [GenRequest(f"c{i}", np.arange(1, 12, dtype=np.int32),
                            max_new_tokens=2) for i in range(3)]
         dones = [srv.submit(r) for r in reqs]
         t0 = time.monotonic()

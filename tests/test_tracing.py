@@ -84,6 +84,7 @@ def served(tmp_path_factory):
     prompts = _prompts(7, cfg.vocab_size)
     plain = _serve_once(srv, prompts)
     steps0, lanes0 = srv.steps, srv.sched.decode_lanes
+    prefills0, tokens0 = srv.sched.prefill_calls, srv.sched.prefill_tokens
     trace_dir = tmp_path_factory.mktemp("serve_trace")
     jax.profiler.start_trace(str(trace_dir))
     try:
@@ -94,6 +95,8 @@ def served(tmp_path_factory):
                spans=_read_spans(trace_dir),
                steps=srv.steps - steps0,
                decode_lanes=srv.sched.decode_lanes - lanes0,
+               prefill_calls=srv.sched.prefill_calls - prefills0,
+               prefill_tokens=srv.sched.prefill_tokens - tokens0,
                sched=srv.scheduler_snapshot())
     srv.close(timeout=60)
     return out
@@ -140,7 +143,7 @@ def _named(spans, name):
 
 @pytest.mark.parametrize("name,args", [
     ("serve.admit", {"admitted"}),
-    ("serve.prefill", {"call", "lanes"}),
+    ("serve.prefill", {"call", "lanes", "tokens"}),
     ("serve.decode", {"call", "step", "lanes"}),
     ("serve.harvest", {"step"}),
     ("serve.sample", {"step"}),
@@ -196,8 +199,10 @@ def _prefill_spans_count_calls(r):
     # with no preemption every prompt but its last token is prefilled,
     # and its last is the first decode step's input
     assert r["sched"].preemptions == 0
-    assert sum(s.args["lanes"] for s in prefills) == \
-        sum(len(p) - 1 for p in r["prompts"])
+    assert len(prefills) == r["prefill_calls"] > 0
+    assert sum(s.args["tokens"] for s in prefills) == \
+        r["prefill_tokens"] == sum(len(p) - 1 for p in r["prompts"])
+    assert f"({r['sched'].prefill_tokens} tokens)" in r["sched"].format()
     assert sum(s.args["admitted"] for s in _named(
         r["spans"], "serve.admit")) == len(r["prompts"])
 
