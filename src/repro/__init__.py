@@ -10,7 +10,7 @@ The package provides:
   compressed gradient reduction.
 * ``repro.models``      — the ten assigned architectures.
 * ``repro.kernels``     — Pallas TPU kernels for the compute hot spots.
-* ``repro.launch``      — production mesh, multi-pod dry-run, train/serve
+* ``repro.launch``      — meshes, the step builder, train/serve
   drivers.
 """
 

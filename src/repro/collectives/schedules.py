@@ -292,7 +292,7 @@ def bruck_alltoall(x: jax.Array, axis: str) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Convenience wrappers (tests / benchmarks)
+# Algorithm registry
 # ---------------------------------------------------------------------------
 
 ALGORITHMS = {

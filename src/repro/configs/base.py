@@ -68,7 +68,8 @@ class ModelConfig:
     attention_impl: str = "xla"
     # pad attention heads up to a multiple of this so the head dim shards
     # over the tensor axis (zero-padded weights receive exactly zero
-    # gradient — model is mathematically unchanged; see EXPERIMENTS §Perf)
+    # gradient, but padding KV heads changes the GQA grouping: see
+    # layers.padded_heads)
     pad_heads_to: int = 0
     attention_chunk: int = 1024
     decode_chunk: int = 4096
@@ -86,8 +87,6 @@ class ModelConfig:
     kv_cache_dtype: str = "bf16"
     # sharding rule overrides for this arch (logical axis -> candidates)
     sharding_overrides: Mapping[str, Sequence[tuple[str, ...]]] | None = None
-    # long-context applicability (full-attention archs skip long_500k)
-    supports_long_context: bool = False
     # logit softcap (grok uses 30.0)
     logit_softcap: float = 0.0
 
@@ -98,7 +97,7 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
     # ------------------------------------------------------------------
-    # Analytical parameter / FLOP counts (used for roofline MODEL_FLOPS)
+    # Analytical parameter counts
     # ------------------------------------------------------------------
     def param_count(self) -> int:
         from repro.models import registry

@@ -20,5 +20,4 @@ def config() -> ModelConfig:
         tie_embeddings=True,
         rms_norm_eps=1e-5,
         ssm=SSMConfig(d_state=128, expand=2, head_dim=64, chunk_size=256),
-        supports_long_context=True,
     )

@@ -26,5 +26,5 @@ def config() -> ModelConfig:
         learned_pos_embed=True,
         frontend_stub="audio",
         rms_norm_eps=1e-5,
-        max_position_embeddings=65536,   # covers decode_32k positions
+        max_position_embeddings=65536,   # covers a 32k-position decode
     )

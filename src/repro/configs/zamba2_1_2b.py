@@ -25,5 +25,4 @@ def config() -> ModelConfig:
         ssm=SSMConfig(d_state=64, expand=2, head_dim=64, chunk_size=256),
         shared_attn_every=6,
         shared_attn_lora_rank=128,
-        supports_long_context=True,
     )

@@ -1,11 +1,11 @@
 """Progress statistics (paper §4.1/§4.4 instrumentation).
 
 The paper's evaluation is built on three observables: progress *latency*
-(benchmarks/_util.py measures that), lock *contention* between threads
-sharing a serial context (Fig 9 vs Fig 11), and wasted *idle spins* —
-sweeps that polled tasks but completed nothing.  This module snapshots
-those counters from streams, subsystems and executor workers into plain
-dataclasses so tests and benchmarks can assert on them (e.g. "two
+(the benchmark reads it from the device trace), lock *contention* between
+threads sharing a serial context (Fig 9 vs Fig 11), and wasted *idle
+spins* — sweeps that polled tasks but completed nothing.  This module
+snapshots those counters from streams, subsystems and executor workers
+into plain dataclasses so tests can assert on them (e.g. "two
 workers on disjoint streams ⇒ zero cross-stream contention").
 
 Counters are incremented without locks on the hot path: every mutation
@@ -166,7 +166,7 @@ def collect(engine: "ProgressEngine",
 
 
 def format_stats(stats: EngineStats) -> str:
-    """Human-readable table (benchmarks / --verbose launchers)."""
+    """Human-readable table (the serving launcher's ``--stats``)."""
     lines = ["stream             polls  compl  contend  idle  errs  pending"]
     for s in stats.streams:
         lines.append(f"{s.name:<18} {s.polls:>5}  {s.completions:>5}  "

@@ -1,20 +1,13 @@
-"""Production mesh construction.
+"""Mesh construction for the launchers and tests.
 
-Target: TPU v5e pods of 16×16 = 256 chips; the multi-pod configuration is
-2 pods = 512 chips with a leading "pod" axis.  Defined as functions so
-importing this module never touches JAX device state.
+Defined as functions so importing this module never touches JAX device
+state.
 """
 from __future__ import annotations
 
 import jax
 
 from repro import compat
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
@@ -28,14 +21,3 @@ def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     data = min(data, n)
     model = max(1, min(model, n // max(data, 1)))
     return make_mesh((data, model), ("data", "model"))
-
-
-# Hardware constants for the roofline (TPU v5e).
-TPU_V5E = {
-    "name": "tpu-v5e",
-    "peak_flops_bf16": 197e12,      # per chip
-    "hbm_bytes_per_s": 819e9,       # per chip
-    "ici_bytes_per_s_per_link": 50e9,
-    "ici_links_per_chip": 4,        # 2D torus on v5e
-    "hbm_bytes": 16e9,
-}

@@ -1,8 +1,12 @@
-"""Step builders shared by the dry-run, the trainer, and the server.
+"""The step builder behind the trainer and the lowering tests.
 
-``build_cell`` returns, for one (arch × shape × mesh) cell, the jitted
-step function plus the abstract inputs and shardings — everything
-``.lower()`` needs, with zero device allocation.
+``build_cell`` returns, for one (arch × shape × mesh) cell, the step
+function plus the abstract inputs and shardings — everything
+``.lower()`` needs, with zero device allocation.  The launcher's
+``Trainer`` and the benchmark's training driver jit its ``train`` step;
+the ``prefill`` and ``decode`` steps (``registry.forward`` and the
+monolithic ``registry.decode_step``) are lowered only by
+``tests/test_lowering.py``.
 """
 from __future__ import annotations
 
@@ -63,36 +67,18 @@ def batch_shardings(batch_specs, mesh):
 def build_cell(cfg: ModelConfig, shape: ShapeSpec, mesh,
                opt_cfg: opt.AdamWConfig | None = None,
                *, microbatches: int = 1,
-               cast_params_bf16: bool = False,
-               decode_weight_stationary: bool = False,
-               int8_weights: bool = False,
-               rules_overrides: dict | None = None) -> Cell:
+               cast_params_bf16: bool = False) -> Cell:
     """Build one (arch × shape × mesh) cell.
 
-    Optimization knobs (all default OFF — the baseline):
-    * microbatches            — gradient accumulation; activation memory
-                                and logits buffers shrink ×m.
-    * cast_params_bf16        — cast f32 master params to bf16 *before*
-                                the model consumes them, so FSDP
-                                all-gathers move half the bytes.
-    * decode_weight_stationary — serve_step keeps 2D-sharded weights
-                                resident and replicates the (tiny) token
-                                activations over the data axis instead of
-                                gathering weights every step (Pope et al.
-                                2D weight-stationary inference layout).
-    * int8_weights            — serving only: large weight matrices stored
-                                per-channel int8 + f32 scales; the
-                                dequantizing convert fuses into consumers,
-                                halving the per-token HBM weight stream.
+    Training options (both default off; the launcher's
+    ``--microbatches`` and ``--cast-bf16``):
+    * microbatches      — gradient accumulation; activation memory and
+                          logits buffers shrink ×m.
+    * cast_params_bf16  — cast f32 master params to bf16 *before* the
+                          model consumes them, so FSDP all-gathers move
+                          half the bytes.
     """
     rules = merged_rules(cfg.sharding_overrides)
-    if decode_weight_stationary and shape.kind == "decode":
-        rules.update({
-            "batch": ((),),          # activations replicated over data
-            "act_heads": ((),), "act_kv_heads": ((),),
-        })
-    if rules_overrides:
-        rules.update(rules_overrides)   # explicit --rules wins
     with axis_rules(rules):
         p_shapes = registry.param_shapes(cfg)
         p_axes = registry.param_axes(cfg)
@@ -171,27 +157,6 @@ def build_cell(cfg: ModelConfig, shape: ShapeSpec, mesh,
             c_shapes = registry.cache_shapes(cfg, shape.global_batch, shape.seq_len)
             c_axes = registry.cache_axes(cfg, shape.global_batch, shape.seq_len)
             c_shard = _shardings_for(c_axes, c_shapes, mesh)
-
-            if int8_weights:
-                from repro.serve import quantization as QZ
-                qp_shapes = QZ.quantized_shapes(p_shapes)
-                qp_axes = QZ.quantized_axes(p_axes, p_shapes)
-                qp_shard = _shardings_for(qp_axes, qp_shapes, mesh)
-
-                def serve_step_q(qparams, cache, batch):
-                    with axis_rules(rules):
-                        params = QZ.dequantize_tree(qparams,
-                                                    jnp.dtype(cfg.dtype))
-                        logits, cache = registry.decode_step(
-                            params, cfg, cache, batch["tokens"], batch["pos"])
-                        return logits, cache
-
-                return Cell(
-                    cfg, shape, mesh, serve_step_q,
-                    (qp_shapes, c_shapes, b_specs),
-                    (qp_shard, c_shard, b_shard),
-                    (None, c_shard),
-                )
 
             def serve_step(params, cache, batch):
                 with axis_rules(rules):

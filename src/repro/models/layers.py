@@ -5,13 +5,13 @@ Conventions:
 * Parameters live in nested dicts of ``jnp`` arrays.  The *structure* is
   declared once as a tree of :class:`PSpec` (shape + logical axes + init);
   ``init_tree`` / ``axes_tree`` / ``shapes_tree`` derive everything else,
-  so the dry-run never has to materialize parameters.
+  so lowering a step never has to materialize parameters.
 * Layers that are scanned over carry a leading ``"layers"`` axis.
 * Compute dtype is ``cfg.dtype`` (bf16 by default); softmax, norms and
   accumulations are f32.
 * Attention here is the **XLA path**: a chunked online-softmax scan whose
-  memory profile matches the Pallas flash kernel (``repro.kernels``) — the
-  dry-run/roofline therefore reflects flash-attention-like HLO bytes.
+  memory profile matches the Pallas flash kernel (``repro.kernels``), so
+  the lowered HLO moves flash-attention-like bytes.
 """
 from __future__ import annotations
 
@@ -421,7 +421,7 @@ def padded_heads(cfg) -> tuple[int, int]:
 
     CAVEAT: padding both H and KVH changes the GQA q→kv grouping, so this
     is an *architecture variant* for TP experiments, not an equivalence-
-    preserving transform (see EXPERIMENTS §Perf notes).  The semantics-
+    preserving transform.  The semantics-
     preserving route to sharded attention for awkward head counts is ring
     attention (``attention_impl="ring"``), which shards the sequence.
     """
@@ -552,8 +552,8 @@ def _moe_expert_block(xg, dispatch, combine, wi_gate, wi_up, wo):
     # The explicit block imposes expert-internal TP (F over `model`).
     # Worth it only for wide experts (grok: F/tp = 2048); for many-tiny-
     # expert MoEs (granite: F/tp = 32) the [g,t,E,C] combine-space psums
-    # exceed the savings — measured in EXPERIMENTS §Perf B — so fall back
-    # to the capacity-sharded einsum formulation.
+    # cost more than the narrow per-shard matmuls save, so fall back to
+    # the capacity-sharded einsum formulation.
     if mesh.empty or tp == 1 or F % tp != 0 \
             or F // tp < 512 or not in_training():
         xe = jnp.einsum("gtec,gtd->gecd", dispatch, xg)

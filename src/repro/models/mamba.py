@@ -11,8 +11,8 @@ TPU adaptation notes (see DESIGN.md §6):
   intra-chunk "attention" term + inter-chunk recurrent state carried by
   ``lax.scan``.  ``repro.kernels.ssd_scan`` is the Pallas version of the
   intra-chunk block.
-* Decode keeps an O(1) recurrent state ``h [B, nh, hp, ds]`` — this is
-  what makes ``long_500k`` run where full attention cannot.
+* Decode keeps an O(1) recurrent state ``h [B, nh, hp, ds]`` — its memory
+  does not grow with the context, where a full-attention KV cache does.
 """
 from __future__ import annotations
 

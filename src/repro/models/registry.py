@@ -1,11 +1,9 @@
-"""Uniform model API across families + analytical parameter/FLOP counts."""
+"""Uniform model API across families + analytical parameter counts."""
 from __future__ import annotations
 
-import math
 from types import ModuleType
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -152,7 +150,7 @@ def reset_paged_lane(cfg, cache, lane_index):
 
 
 # ---------------------------------------------------------------------------
-# Analytical counts (roofline MODEL_FLOPS)
+# Analytical parameter counts
 # ---------------------------------------------------------------------------
 
 def _spec_leaves_with_path(cfg):
@@ -173,76 +171,3 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
             n = int(n * cfg.moe.top_k / cfg.moe.num_experts)
         total += n
     return total
-
-
-def non_embedding_param_count(cfg: ModelConfig, active_only: bool = False) -> int:
-    total = 0
-    for path, leaf in _spec_leaves_with_path(cfg):
-        if "embed" in path.split("/")[-1] or "lm_head" in path:
-            continue
-        n = int(np.prod(leaf.shape))
-        if active_only and cfg.moe is not None and "/moe/" in f"/{path}/" \
-                and "router" not in path:
-            n = int(n * cfg.moe.top_k / cfg.moe.num_experts)
-        total += n
-    return total
-
-
-def _encoder_param_count(cfg: ModelConfig) -> int:
-    total = 0
-    for path, leaf in _spec_leaves_with_path(cfg):
-        if path.startswith("encoder/") or "/encoder/" in path:
-            total += int(np.prod(leaf.shape))
-    return total
-
-
-def model_flops(cfg: ModelConfig, tokens: int, *, training: bool,
-                include_attention: bool = True, seq_len: int = 0,
-                decode_cache_len: int = 0) -> float:
-    """Canonical 6·N·D (train) / 2·N·D (inference) + attention term.
-
-    N counts *active* parameters (MoE); attention adds the 12·L·d·S
-    (kernel) term when seq_len is given. For decode, the attention term
-    uses the cache length per produced token.
-    """
-    n_active = param_count(cfg, active_only=True)
-    mult = 6.0 if training else 2.0
-    if cfg.is_encoder_decoder and seq_len:
-        # encoder params run over `encoder_frames` tokens, not seq_len
-        enc = _encoder_param_count(cfg)
-        batch = tokens / max(seq_len, 1)
-        flops = mult * ((n_active - enc) * tokens
-                        + enc * batch * cfg.encoder_frames)
-    else:
-        flops = mult * n_active * tokens
-    if include_attention:
-        hd = cfg.resolved_head_dim() if cfg.num_heads else 0
-        att_layers = cfg.num_layers + cfg.num_encoder_layers
-        if cfg.family == "hybrid":
-            att_layers = cfg.num_layers // max(cfg.shared_attn_every, 1)
-        if cfg.num_heads and seq_len:
-            batch = tokens / max(seq_len, 1)
-            k = 3.0 if training else 1.0
-            if cfg.is_encoder_decoder:
-                F = cfg.encoder_frames
-                dec = (2 * 2 * seq_len * seq_len / 2      # causal self
-                       + 2 * 2 * seq_len * F)             # cross
-                enc = 2 * 2 * F * F
-                flops += k * batch * cfg.num_heads * hd * (
-                    cfg.num_layers * dec + cfg.num_encoder_layers * enc)
-            else:
-                # 2·S²·H·hd (scores) + same (values), causal halves it
-                per_layer = 2 * 2 * seq_len * seq_len * cfg.num_heads * hd / 2
-                flops += k * batch * att_layers * per_layer
-        if cfg.num_heads and decode_cache_len:
-            per_tok = 2 * 2 * decode_cache_len * cfg.num_heads * hd
-            flops += tokens * att_layers * per_tok
-        if cfg.ssm is not None and seq_len:
-            from repro.models import mamba as M
-            d_inner, nh, hp, ds = M.dims(cfg)
-            Q = cfg.ssm.chunk_size
-            ssm_layers = cfg.num_layers if cfg.family != "hybrid" else cfg.num_layers
-            # intra-chunk (2·Q·nh·hp per token) + state/out (4·nh·hp·ds)
-            per_tok = 2 * Q * nh * hp + 4 * nh * hp * ds
-            flops += (3.0 if training else 1.0) * tokens * ssm_layers * per_tok
-    return float(flops)

@@ -1,10 +1,10 @@
 """int8 weight quantization for serving.
 
-Decode is weight-read-bound (EXPERIMENTS §Perf C): per token step every
-parameter is streamed from HBM once.  Storing the large 2-D+ weight
-matrices as per-output-channel int8 with f32 scales halves that stream
-vs bf16 (and ×4 vs f32) — XLA fuses the dequantizing convert into the
-consuming matmul, so the int8 bytes are what cross HBM.
+Decode is weight-read-bound: per token step every parameter is streamed
+from HBM once.  Storing the large 2-D+ weight matrices as per-output-
+channel int8 with f32 scales halves that stream vs bf16 (and ×4 vs f32)
+— XLA fuses the dequantizing convert into the consuming matmul, so the
+int8 bytes are what cross HBM.
 
 ``quantize_tree`` walks a parameter pytree and replaces eligible leaves
 (float, ndim ≥ 2, above a size threshold) with ``QuantizedTensor``
@@ -63,7 +63,7 @@ def quantized_bytes(params: Any) -> int:
 
 
 def quantized_shapes(param_shapes: Any, min_size: int = 1 << 16) -> Any:
-    """ShapeDtypeStruct version for the dry-run (no allocation)."""
+    """ShapeDtypeStruct version, for lowering without allocation."""
     def one(p):
         if _eligible(p, min_size):
             return QuantizedTensor(

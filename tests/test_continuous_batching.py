@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import trend
 from repro.configs import get_config
 from repro.core import ProgressEngine
 from repro.models import registry
@@ -597,35 +596,3 @@ def test_trace_ssm_concurrency_consistent():
                                 max_seq=32, **kw)
         assert got == serial, arch
         assert lat.completed == 6
-
-
-# ---------------------------------------------------------------------------
-# Trend gate: serve_cb rows are tracked, ratio rows are not
-# ---------------------------------------------------------------------------
-
-class TestTrendServeCbRows:
-    def _summary(self, rows):
-        return {"schema": "repro-bench-v1", "git_rev": "x",
-                "rows": [{"name": n, "us_per_call": v, "derived": ""}
-                         for n, v in rows]}
-
-    def test_serve_cb_rows_tracked(self, tmp_path):
-        import json
-        prev = tmp_path / "prev.json"
-        cur = tmp_path / "cur.json"
-        prev.write_text(json.dumps(self._summary(
-            [("serve_cb_ttft_paged", 1000.0),
-             ("serve_cb_p99_lane4", 5000.0),
-             ("cb_gain_concurrency", 3.0)])))
-        cur.write_text(json.dumps(self._summary(
-            [("serve_cb_ttft_paged", 2500.0),      # regressed
-             ("serve_cb_p99_lane4", 5100.0),       # ok
-             ("cb_gain_concurrency", 1.0)])))      # ratio: untracked
-        prev_rows = trend.load_rows(str(prev), trend.DEFAULT_PREFIXES)
-        cur_rows = trend.load_rows(str(cur), trend.DEFAULT_PREFIXES)
-        assert "serve_cb_ttft_paged" in prev_rows
-        assert "cb_gain_concurrency" not in prev_rows
-        by_name = {e["name"]: e
-                   for e in trend.compare(prev_rows, cur_rows, 0.2)}
-        assert by_name["serve_cb_ttft_paged"]["status"] == "regressed"
-        assert by_name["serve_cb_p99_lane4"]["status"] == "ok"

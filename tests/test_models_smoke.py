@@ -1,6 +1,8 @@
 """Per-architecture smoke tests: reduced config of the same family, one
 forward/train step + one decode step on CPU, asserting shapes + finiteness.
-(The FULL configs are exercised only via the dry-run, per the assignment.)
+(tests/test_lowering.py compiles every architecture's steps on a
+multi-device mesh; tests/test_chip_compile.py compiles qwen2-0.5b at its
+published widths for a described v5e.)
 """
 import jax
 import jax.numpy as jnp

@@ -7,7 +7,6 @@ sums are exact and results can be asserted *bit-identical* to the native
 op.  Handle lifecycle — one outstanding start, failure-then-restart,
 cancel, close — runs in-process against fake host-callable plans.
 """
-import json
 import random
 import types
 
@@ -364,89 +363,3 @@ def test_persistent_restart_random_drains():
         assert req.value() == trial + 3.0
     assert counts == [[1, 1, 1]] * 20
     coll.close()
-
-
-# ---------------------------------------------------------------------------
-# Satellites: run.py section validation + trend gate
-# ---------------------------------------------------------------------------
-
-def test_run_py_unknown_section_errors():
-    from benchmarks import run as bench_run
-    with pytest.raises(SystemExit) as exc:
-        bench_run.main(["--sections", "nope", "--json", ""])
-    assert "unknown section" in str(exc.value)
-
-
-def _summary(rev, rows):
-    return {"schema": "repro-bench-v1", "git_rev": rev,
-            "rows": [{"section": "s", "name": k, "us_per_call": v,
-                      "derived": ""} for k, v in rows.items()]}
-
-
-class TestTrendGate:
-    def write(self, tmp_path, prev_rows, cur_rows):
-        prev = tmp_path / "prev.json"
-        cur = tmp_path / "cur.json"
-        prev.write_text(json.dumps(_summary("aaa", prev_rows)))
-        cur.write_text(json.dumps(_summary("bbb", cur_rows)))
-        return str(prev), str(cur)
-
-    def test_regression_flagged_and_exits_nonzero(self, tmp_path):
-        from benchmarks import trend
-        prev, cur = self.write(
-            tmp_path,
-            {"fig7_pending_1": 100.0, "fig14_user_x": 50.0},
-            {"fig7_pending_1": 130.0, "fig14_user_x": 50.0})
-        summary = tmp_path / "step_summary.md"
-        rc = trend.main(["--current", cur, "--previous", prev,
-                         "--summary", str(summary)])
-        assert rc == 1
-        text = summary.read_text()
-        assert "regressed" in text and "fig7_pending_1" in text
-        assert "+30.0%" in text
-
-    def test_improvement_and_noise_pass(self, tmp_path):
-        from benchmarks import trend
-        prev, cur = self.write(
-            tmp_path,
-            {"fig13_cb_1": 100.0, "fig14_user_y": 200.0},
-            {"fig13_cb_1": 110.0, "fig14_user_y": 40.0})  # +10%, -80%
-        rc = trend.main(["--current", cur, "--previous", prev,
-                         "--summary", ""])
-        assert rc == 0
-
-    def test_untracked_and_ratio_rows_ignored(self, tmp_path):
-        from benchmarks import trend
-        prev, cur = self.write(
-            tmp_path,
-            {"kernel_matmul": 10.0, "fig14_persistent_gain_x": 1.0},
-            {"kernel_matmul": 900.0, "fig14_persistent_gain_x": 9.0})
-        rc = trend.main(["--current", cur, "--previous", prev,
-                         "--summary", ""])
-        assert rc == 0                       # neither row is tracked
-
-    def test_new_and_gone_rows_do_not_gate(self, tmp_path):
-        from benchmarks import trend
-        prev, cur = self.write(tmp_path,
-                               {"fig7_old_row": 10.0},
-                               {"fig7_new_row": 10.0})
-        rc = trend.main(["--current", cur, "--previous", prev,
-                         "--summary", ""])
-        assert rc == 0
-
-    def test_missing_previous_is_not_an_error(self, tmp_path):
-        from benchmarks import trend
-        _, cur = self.write(tmp_path, {}, {"fig7_x": 1.0})
-        summary = tmp_path / "s.md"
-        rc = trend.main(["--current", cur,
-                         "--previous", str(tmp_path / "absent.json"),
-                         "--summary", str(summary)])
-        assert rc == 0
-        assert "nothing to compare" in summary.read_text()
-
-    def test_missing_current_errors(self, tmp_path):
-        from benchmarks import trend
-        rc = trend.main(["--current", str(tmp_path / "absent.json"),
-                         "--previous", str(tmp_path / "also_absent.json"),
-                         "--summary", ""])
-        assert rc == 2

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import trend
 from repro.collectives import nonblocking as NB
 from repro.collectives.overlap import bucket_tree
 from repro.configs import get_config
@@ -433,37 +432,3 @@ def test_mixed_dtype_allreduce_tree_matches_psum(n_devices):
         print("MIXED_DTYPE_TREE_OK")
     """, n_devices=n_devices)
     assert "MIXED_DTYPE_TREE_OK" in out
-
-
-# ---------------------------------------------------------------------------
-# Trend gate: serve_decode rows are tracked, serve_gain ratios are not
-# ---------------------------------------------------------------------------
-
-class TestTrendServeRows:
-    def _summary(self, rows):
-        return {"schema": "repro-bench-v1", "git_rev": "x",
-                "rows": [{"name": n, "us_per_call": v, "derived": ""}
-                         for n, v in rows]}
-
-    def test_serve_rows_in_default_prefixes(self, tmp_path):
-        import json
-        prev = tmp_path / "prev.json"
-        cur = tmp_path / "cur.json"
-        prev.write_text(json.dumps(self._summary(
-            [("serve_decode_user_m2", 100.0),
-             ("serve_gain_user_vs_native_m2", 1.5),
-             ("fig7_pending_1", 1.0)])))
-        cur.write_text(json.dumps(self._summary(
-            [("serve_decode_user_m2", 200.0),          # 2x slower
-             ("serve_gain_user_vs_native_m2", 0.1),    # ratio: untracked
-             ("fig7_pending_1", 1.0)])))
-        prev_rows = trend.load_rows(str(prev), trend.DEFAULT_PREFIXES)
-        cur_rows = trend.load_rows(str(cur), trend.DEFAULT_PREFIXES)
-        assert "serve_decode_user_m2" in prev_rows
-        assert "serve_gain_user_vs_native_m2" not in prev_rows
-        entries = trend.compare(prev_rows, cur_rows, 0.2)
-        by_name = {e["name"]: e for e in entries}
-        assert by_name["serve_decode_user_m2"]["status"] == "regressed"
-        assert by_name["fig7_pending_1"]["status"] == "ok"
-        rc = trend.main(["--current", str(cur), "--previous", str(prev)])
-        assert rc == 1                         # regression annotates
